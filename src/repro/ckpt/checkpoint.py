@@ -81,7 +81,10 @@ def _encode(arr: np.ndarray):
                 return arr, str(dt)
         except TypeError:
             pass
-    return arr.view(np.uint8).reshape(arr.shape + (dt.itemsize,)), str(dt)
+    # a device array's host copy need not be C-ordered (TPU layouts), and a
+    # byte view needs a contiguous last axis
+    raw = np.ascontiguousarray(arr).view(np.uint8)
+    return raw.reshape(arr.shape + (dt.itemsize,)), str(dt)
 
 
 def _decode(arr: np.ndarray, dtype_name: str) -> np.ndarray:

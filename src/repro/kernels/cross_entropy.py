@@ -10,11 +10,14 @@ used in training).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -37,15 +40,15 @@ def _ce_kernel(h_ref, w_ref, t_ref, nll_ref, m_scr, l_scr, tgt_scr, *,
         jnp.int32, (block_t, block_v), 1)
     logits = jnp.where(vids < V, logits, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, logits.max(axis=-1))
+    m_prev = m_scr[...]                         # (bT, 1)
+    m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
     l_scr[...] = (l_scr[...] * jnp.exp(m_prev - m_new)
-                  + jnp.exp(logits - m_new[:, None]).sum(axis=-1))
+                  + jnp.exp(logits - m_new).sum(axis=-1, keepdims=True))
     m_scr[...] = m_new
 
-    tgt = t_ref[...]                            # (bT,) int32
-    hit = vids == tgt[:, None]
-    tgt_scr[...] = tgt_scr[...] + jnp.where(hit, logits, 0.0).sum(axis=-1)
+    hit = vids == t_ref[...]                    # (bT, bV) vs (bT, 1) int32
+    tgt_scr[...] = tgt_scr[...] + jnp.where(hit, logits, 0.0).sum(
+        axis=-1, keepdims=True)
 
     @pl.when(vi == n_v - 1)
     def _emit():
@@ -61,7 +64,7 @@ def cross_entropy_pallas(
     *,
     block_t: int = 256,
     block_v: int = 2048,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     T, D = hidden.shape
     V = w_vocab.shape[0]
@@ -82,17 +85,13 @@ def cross_entropy_pallas(
         in_specs=[
             pl.BlockSpec((block_t, D), lambda ti, vi: (ti, 0)),
             pl.BlockSpec((block_v, D), lambda ti, vi: (vi, 0)),
-            pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
+            pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
         ],
-        out_specs=pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
-        out_shape=jax.ShapeDtypeStruct((Tp,), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((block_t,), jnp.float32),
-            pltpu.VMEM((block_t,), jnp.float32),
-            pltpu.VMEM((block_t,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(h, w, t.astype(jnp.int32))[:T]
+        out_specs=pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
+        out_shape=jax.ShapeDtypeStruct((Tp, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_t, 1), jnp.float32)] * 3,
+        interpret=resolve_interpret(interpret),
+    )(h, w, t.astype(jnp.int32)[:, None])[:T, 0]
 
     if valid is not None:
         v = valid.astype(jnp.float32)

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -89,7 +91,7 @@ def flash_attention_pallas(
     block_q: int = 512,
     block_k: int = 512,
     scale: Optional[float] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
@@ -145,7 +147,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt, qo, kl)
 
     out = out.reshape(B, Hq, Sqp, D)[:, :, :Sq].transpose(0, 2, 1, 3)

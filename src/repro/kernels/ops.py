@@ -2,33 +2,31 @@
 
 The model zoo calls these entry points exclusively.  ``use_pallas=False``
 (CPU smoke tests, the 512-device dry-run) routes to ``ref.py``;
-``use_pallas=True`` routes to the Pallas kernels (TPU target; validated on
-CPU via interpret=True in tests).
+``use_pallas=True`` routes to the Pallas kernels, which Mosaic compiles on
+the TPU and which run in interpret mode where the default backend is the
+CPU (``kernels.resolve_interpret``).
 """
 from __future__ import annotations
-
-
 
 from repro.kernels import ref as _ref
 
 
-def rmsnorm(x, w, *, eps: float = 1e-6, use_pallas: bool = False,
-            interpret: bool = True):
+def rmsnorm(x, w, *, eps: float = 1e-6, use_pallas: bool = False):
     if use_pallas:
         from repro.kernels.rmsnorm import rmsnorm_pallas
-        return rmsnorm_pallas(x, w, eps=eps, interpret=interpret)
+        return rmsnorm_pallas(x, w, eps=eps)
     return _ref.rmsnorm_ref(x, w, eps)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, q_offset=0, kv_len=None,
                     sliding_window: int = 0, block_k: int = 512,
-                    use_pallas: bool = False, interpret: bool = True,
-                    carry_constrain=None, custom_vjp: bool = True):
+                    use_pallas: bool = False, carry_constrain=None,
+                    custom_vjp: bool = True):
     if use_pallas:
         from repro.kernels.flash_attention import flash_attention_pallas
         return flash_attention_pallas(
             q, k, v, causal=causal, q_offset=q_offset, kv_len=kv_len,
-            sliding_window=sliding_window, interpret=interpret)
+            sliding_window=sliding_window)
     return _ref.flash_attention_ref(
         q, k, v, causal=causal, q_offset=q_offset, kv_len=kv_len,
         sliding_window=sliding_window, block_k=block_k,
@@ -36,13 +34,11 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset=0, kv_len=None,
 
 
 def ssd(x, dt, A, Bm, Cm, *, chunk: int = 128, init_state=None,
-        return_state: bool = False, use_pallas: bool = False,
-        interpret: bool = True):
+        return_state: bool = False, use_pallas: bool = False):
     if use_pallas:
         from repro.kernels.ssd_scan import ssd_pallas
         return ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk,
-                          init_state=init_state, return_state=return_state,
-                          interpret=interpret)
+                          init_state=init_state, return_state=return_state)
     return _ref.ssd_ref(x, dt, A, Bm, Cm, chunk=chunk,
                         init_state=init_state, return_state=return_state)
 
@@ -54,11 +50,11 @@ def ssd_decode(x, dt, A, Bm, Cm, h):
 
 def cross_entropy(hidden, w_vocab, targets, valid=None, *,
                   mode: str = "direct", block_v: int = 4096,
-                  use_pallas: bool = False, interpret: bool = True):
+                  use_pallas: bool = False):
     if use_pallas:
         from repro.kernels.cross_entropy import cross_entropy_pallas
         return cross_entropy_pallas(hidden, w_vocab, targets, valid,
-                                    block_v=block_v, interpret=interpret)
+                                    block_v=block_v)
     if mode == "blockwise":
         return _ref.cross_entropy_blockwise_ref(hidden, w_vocab, targets,
                                                 valid, block_v=block_v)

@@ -1,8 +1,9 @@
 """Pure-jnp reference oracles for every Pallas kernel.
 
-These are ALSO the XLA execution path used by the model zoo on CPU and in
-the 512-device dry-run (Pallas targets TPU; ``interpret=True`` validates
-the kernels against these functions in tests).
+These are ALSO the XLA execution path of the model zoo
+(``RunConfig.use_pallas=False``), on the CPU, on the TPU and in the
+512-device dry-run. Tests compare the kernels against these functions,
+interpreted on the CPU and compiled on the TPU (``chip_smoke.py``).
 
 The attention reference is itself written flash-style (chunked online
 softmax over KV blocks) so that (a) it is the mathematical oracle for the

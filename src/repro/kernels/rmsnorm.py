@@ -9,10 +9,13 @@ intermediate ever round-trips to HBM (the XLA ref materializes x.astype
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -23,7 +26,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_pallas(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
-                   block_rows: int = 256, interpret: bool = True
+                   block_rows: int = 256, interpret: Optional[bool] = None
                    ) -> jax.Array:
     """x: (..., D); w: (D,)."""
     orig_shape = x.shape
@@ -45,6 +48,6 @@ def rmsnorm_pallas(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((bR, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, D), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xf, w)
     return out[:R].reshape(orig_shape)

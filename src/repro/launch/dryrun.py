@@ -27,29 +27,16 @@ from repro.launch import mesh as mesh_lib
 from repro.models import params as P
 from repro.models import registry
 from repro.serve import engine
-from repro.sharding import ShardingRules, param_shardings, use_rules
+from repro.sharding import (ShardingRules, batch_shardings, param_shardings,
+                            use_rules)
 from repro.train import step as train_step_lib
 
 
-def batch_shardings(rules: ShardingRules, specs: Dict[str, Any]):
-    """Inputs: shard the leading batch dim on (pod, data); rest replicated."""
-    def one(s):
-        if not hasattr(s, "shape") or len(s.shape) == 0:
-            return rules.sharding((), ())
-        logical = ["batch"] + [None] * (len(s.shape) - 1)
-        return rules.sharding(logical, s.shape)
-    return jax.tree.map(one, specs)
-
-
-def state_shardings(cfg, run, rules: ShardingRules):
-    defs = registry.param_defs(cfg)
-    p_sh = param_shardings(defs, rules)
-    return {
-        "params": p_sh,
-        "opt": {"m": jax.tree.map(lambda s: s, p_sh),
-                "v": jax.tree.map(lambda s: s, p_sh),
-                "step": rules.sharding((), ())},
-    }
+def host_production_mesh(multi_pod: bool):
+    """Production mesh over the forced host devices, never the default
+    backend: on a TPU machine that backend has only the attached chips."""
+    return mesh_lib.make_production_mesh(multi_pod=multi_pod,
+                                         devices=jax.devices("cpu"))
 
 
 def cache_shardings(cfg, rules: ShardingRules, batch: int, max_len: int):
@@ -83,7 +70,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
     with use_rules(rules):
         if shape.kind == "train":
             state_abs = train_step_lib.abstract_state(cfg, run)
-            st_sh = state_shardings(cfg, run, rules)
+            st_sh = train_step_lib.state_shardings(cfg, rules)
             b_sh = batch_shardings(rules, specs)
             fn = train_step_lib.make_train_step(cfg, run)
             lowered = jax.jit(
@@ -139,7 +126,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                 "reason": why}
     t0 = time.time()
     if mesh is None:
-        mesh = mesh_lib.make_production_mesh(multi_pod=multi_pod)
+        mesh = host_production_mesh(multi_pod)
     n_chips = mesh.devices.size
     try:
         lowered, meta = lower_cell(arch, shape_name, mesh,
@@ -149,7 +136,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         compiled = lowered.compile()
         t_compile = time.time() - t1
         mem = compiled.memory_analysis()
-        cost = hlo_cost.xla_cost(compiled)
+        cost = compiled.cost_analysis()
         # our walker: per-device flops/bytes with while-loop trip counts
         # (XLA's cost_analysis counts loop bodies once — see hlo_cost.py)
         walk = hlo_cost.analyze(compiled.as_text()) if collect_hlo else {}
@@ -254,7 +241,7 @@ def main(argv=None) -> int:
     results = []
     n_bad = 0
     for mp in meshes:
-        mesh = mesh_lib.make_production_mesh(multi_pod=mp)
+        mesh = host_production_mesh(mp)
         for arch, shape in cells:
             r = run_cell(arch, shape, multi_pod=mp, mesh=mesh,
                          run_overrides=overrides)

@@ -429,12 +429,3 @@ def analyze(text: str) -> Dict[str, float]:
     for k, v in c.collectives.items():
         out[f"coll_{k}"] = v
     return out
-
-
-def xla_cost(compiled) -> Dict[str, float]:
-    """XLA's own ``compiled.cost_analysis()``, normalized across jax
-    versions (older releases return a list with one dict per program)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost

@@ -14,10 +14,12 @@ import jax.numpy as jnp
 
 from repro.configs.base import (RunConfig, ShapeConfig, get_config,
                                 get_smoke_config)
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import registry
 from repro.serve import engine
 from repro.sharding import ShardingRules, use_rules
+from repro.train.step import init_state
 
 
 def run_serving(arch: str, *, smoke: bool = True, prompt_len: int = 32,
@@ -39,8 +41,7 @@ def run_serving(arch: str, *, smoke: bool = True, prompt_len: int = 32,
     decode = jax.jit(engine.make_decode_step(cfg, run), donate_argnums=(2,))
 
     with use_rules(rules):
-        params = __import__("repro.train.step", fromlist=["init_state"]) \
-            .init_state(jax.random.PRNGKey(1), cfg, run)["params"]
+        params = init_state(jax.random.PRNGKey(1), cfg, run)["params"]
         cache = engine.init_cache(cfg, batch, max_len)
         t0 = time.time()
         tok, cache = prefill(params, prompts, cache)
@@ -75,6 +76,7 @@ def main(argv=None) -> int:
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)
     args = ap.parse_args(argv)
+    use_compile_cache()
     res = run_serving(args.arch, smoke=args.smoke,
                       prompt_len=args.prompt_len, gen=args.gen,
                       batch=args.batch)

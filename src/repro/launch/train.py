@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
 
 from repro.carousel.delivery import DeliveryIterator
 from repro.carousel.stager import Stager
@@ -25,10 +26,11 @@ from repro.ckpt import AsyncCheckpointer, latest_step, load_checkpoint
 from repro.configs.base import (RunConfig, ShapeConfig, get_config,
                                 get_smoke_config)
 from repro.data.synthetic import build_cold_store
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import registry
-from repro.sharding import ShardingRules, param_shardings, use_rules
-from repro.train.step import init_state, make_train_step
+from repro.sharding import ShardingRules, batch_shardings, use_rules
+from repro.train.step import init_state, make_train_step, state_shardings
 
 
 def make_carousel_pipeline(cfg, *, seq_len: int, batch_rows: int,
@@ -52,9 +54,7 @@ def make_carousel_pipeline(cfg, *, seq_len: int, batch_rows: int,
 def _batch_iter_carousel(cfg, shape, delivery) -> Iterator[Dict[str, Any]]:
     extra = _modality_extras(cfg, shape)
     for b in delivery:
-        out = {k: jnp.asarray(v) for k, v in b.items()}
-        out.update(extra)
-        yield out
+        yield {**b, **extra}
 
 
 def _modality_extras(cfg, shape) -> Dict[str, Any]:
@@ -92,42 +92,42 @@ def run_training(
     drives: int = 4,
     run: Optional[RunConfig] = None,
     on_step: Optional[Callable[[int, Dict[str, float]], None]] = None,
+    mesh: Optional[Mesh] = None,
 ) -> Dict[str, Any]:
+    """``mesh``: a ("data", "model") mesh; default: every device of the
+    default backend on the data axis.  State is sharded by the param rules
+    (FSDP on "embed", TP on the model axis) and each batch on "batch"."""
     cfg = get_smoke_config(arch) if smoke else get_config(arch)
     shape = ShapeConfig("train", seq_len, global_batch, "train")
     run = run or RunConfig(total_steps=max(steps, 10), warmup_steps=2,
                            ce_block_v=max(64, cfg.vocab_size // 8))
 
-    mesh = make_host_mesh()
-    rules = ShardingRules(mesh)
-    step_fn = jax.jit(make_train_step(cfg, run), donate_argnums=(0,))
+    rules = ShardingRules(mesh or make_host_mesh())
+    st_sh = state_shardings(cfg, rules)
+    step_fn = jax.jit(make_train_step(cfg, run), out_shardings=(st_sh, None),
+                      donate_argnums=(0,))
 
     start_step = 0
     if resume and out_dir and latest_step(out_dir) is not None:
-        defs = registry.param_defs(cfg)
-        p_sh = param_shardings(defs, rules)
-        shardings = {"params": p_sh,
-                     "opt": {"m": jax.tree.map(lambda s: s, p_sh),
-                             "v": jax.tree.map(lambda s: s, p_sh),
-                             "step": None}}
-        state, meta = load_checkpoint(out_dir, shardings=None)
-        state = jax.tree.map(jnp.asarray, state)
+        state, meta = load_checkpoint(out_dir, shardings=st_sh)
         start_step = int(meta["step"])
     else:
-        state = init_state(jax.random.PRNGKey(0), cfg, run)
+        state = init_state(jax.random.PRNGKey(0), cfg, run, st_sh)
 
     ckpt = AsyncCheckpointer(out_dir, keep=3) if out_dir else None
     stager = None
     if carousel:
+        # about 8 packed rows per shard: stage twice the rows the run uses
         stager, delivery = make_carousel_pipeline(
             cfg, seq_len=seq_len, batch_rows=global_batch,
-            n_shards=max(8, steps), coarse=coarse,
+            n_shards=max(8, -(-steps * global_batch // 4)), coarse=coarse,
             tape_latency=tape_latency, drives=drives)
         batches = _batch_iter_carousel(cfg, shape, delivery)
     else:
         batches = _batch_iter_synth(cfg, shape)
 
     losses: List[float] = []
+    step_s: List[float] = []
     t0 = time.time()
     ttfb = None
     with use_rules(rules):
@@ -135,8 +135,11 @@ def run_training(
         for batch in batches:
             if done >= start_step + steps:
                 break
+            t_step = time.time()
+            batch = jax.device_put(batch, batch_shardings(rules, batch))
             state, metrics = step_fn(state, batch)
             loss = float(metrics["loss"])
+            step_s.append(time.time() - t_step)
             if ttfb is None:
                 ttfb = time.time() - t0
             losses.append(loss)
@@ -157,6 +160,7 @@ def run_training(
         "first_loss": losses[0] if losses else None,
         "last_loss": losses[-1] if losses else None,
         "losses": losses,
+        "step_s": step_s,
         "time_to_first_batch_s": ttfb,
         "wall_s": time.time() - t0,
         "final_step": done,
@@ -178,12 +182,14 @@ def main(argv=None) -> int:
     ap.add_argument("--coarse", action="store_true",
                     help="pre-iDDS baseline: wait for the whole dataset")
     args = ap.parse_args(argv)
+    use_compile_cache()
     res = run_training(args.arch, smoke=args.smoke, steps=args.steps,
                        seq_len=args.seq_len, global_batch=args.global_batch,
                        out_dir=args.out, resume=args.resume,
                        carousel=args.carousel, coarse=args.coarse)
     res.pop("state")
     res.pop("losses")
+    res.pop("step_s")
     print(res)
     return 0
 

@@ -1,10 +1,10 @@
 from repro.sharding.rules import (  # noqa: F401
     DEFAULT_RULES,
     ShardingRules,
+    batch_shardings,
     constrain,
     current_rules,
     param_shardings,
     param_specs,
-    shard_params,
     use_rules,
 )

@@ -144,7 +144,11 @@ def param_shardings(defs: Any, rules: ShardingRules) -> Any:
     return P.tree_map(lambda d: rules.sharding(d.logical, d.shape), defs)
 
 
-def shard_params(arrs: Any, defs: Any, rules: ShardingRules) -> Any:
-    """device_put a materialized param tree with its resolved shardings."""
-    sh = param_shardings(defs, rules)
-    return jax.tree.map(lambda a, s: jax.device_put(a, s), arrs, sh)
+def batch_shardings(rules: ShardingRules, batch: Any) -> Any:
+    """Input-batch shardings: every leaf's leading dim on the "batch" rule,
+    the rest replicated (scalars replicated).  Leaves need only ``.shape``,
+    so this serves abstract specs and host arrays alike."""
+    def one(x):
+        logical = ("batch",) + (None,) * (len(x.shape) - 1) if x.shape else ()
+        return rules.sharding(logical, x.shape)
+    return jax.tree.map(one, batch)

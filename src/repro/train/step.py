@@ -18,16 +18,30 @@ from repro.configs.base import ModelConfig, RunConfig
 from repro.models import params as P
 from repro.models import registry
 from repro.optim import adamw_init, adamw_update, cosine_schedule
+from repro.sharding import ShardingRules, param_shardings
 from repro.train.loss import lm_loss
 
 TrainState = Dict[str, Any]
 
 
-def init_state(rng: jax.Array, cfg: ModelConfig, run: RunConfig) -> TrainState:
-    defs = registry.param_defs(cfg)
-    params = P.materialize(rng, defs)
-    opt = adamw_init(params, dtype=jnp.dtype(run.opt_state_dtype))
-    return {"params": params, "opt": opt}
+def state_shardings(cfg: ModelConfig, rules: ShardingRules) -> TrainState:
+    """NamedSharding tree of the train state: each moment is sharded
+    exactly like its parameter (ZeRO-style); the step is replicated."""
+    p_sh = param_shardings(registry.param_defs(cfg), rules)
+    return {"params": p_sh,
+            "opt": {"m": p_sh, "v": p_sh, "step": rules.sharding((), ())}}
+
+
+def init_state(rng: jax.Array, cfg: ModelConfig, run: RunConfig,
+               shardings: Any = None) -> TrainState:
+    """Params and moments, created under jit so that every leaf is born
+    with its sharding (``shardings`` from ``state_shardings``) and no
+    device ever holds the whole state; None leaves placement to jit."""
+    def init(key):
+        params = P.materialize(key, registry.param_defs(cfg))
+        opt = adamw_init(params, dtype=jnp.dtype(run.opt_state_dtype))
+        return {"params": params, "opt": opt}
+    return jax.jit(init, out_shardings=shardings)(rng)
 
 
 def abstract_state(cfg: ModelConfig, run: RunConfig) -> TrainState:

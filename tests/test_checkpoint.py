@@ -42,6 +42,18 @@ def test_round_trip(tmp_path):
     assert meta["loss"] == 1.5 and meta["step"] == 120
 
 
+def test_round_trip_non_contiguous_bf16(tmp_path):
+    """Host copies of device arrays may come back in another memory order
+    (TPU layouts); the raw-bits encoding of bfloat16 must still work, as
+    must a 0-d bfloat16 leaf."""
+    base = np.arange(24, dtype=np.float32).reshape(4, 6)
+    t = {"w": base.astype(jnp.bfloat16).T, "s": np.asarray(3.5, jnp.bfloat16)}
+    assert not t["w"].flags.c_contiguous
+    save_checkpoint(str(tmp_path), t, 1)
+    t2, _ = load_checkpoint(str(tmp_path))
+    _assert_tree_equal(t, t2)
+
+
 def test_latest_step_and_overwrite(tmp_path):
     save_checkpoint(str(tmp_path), _tree(), 1)
     save_checkpoint(str(tmp_path), _tree(), 3)

@@ -34,7 +34,7 @@ def test_scan_multiplies_by_trip_count():
         assert res["flops"] == n * 2 * 64 * 64 * 64, n
         # XLA's own analysis counts the body once — that's the bug we fix
         if n > 1:
-            assert hlo_cost.xla_cost(c)["flops"] < res["flops"]
+            assert c.cost_analysis()["flops"] < res["flops"]
 
 
 def test_nested_scan():

@@ -1,5 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps + hypothesis
-property tests (interpret=True executes the kernel body on CPU)."""
+property tests (on the CPU backend the kernels run in interpret mode,
+which executes the kernel body in Python)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

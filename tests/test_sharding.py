@@ -94,3 +94,26 @@ def test_constrain_noop_without_context():
     from repro.sharding import constrain
     x = jnp.ones((4, 4))
     assert constrain(x, "batch", None) is x
+
+
+def test_launch_meshes_are_auto_and_constrain_under_jit():
+    """Every mesh builder gives Auto axes, so ``constrain`` (which calls
+    ``with_sharding_constraint``) works inside jit with rules in effect."""
+    from jax.sharding import AxisType
+    from repro.launch import mesh as mesh_lib
+    from repro.sharding import constrain, use_rules
+
+    # production shapes need 256/512 devices: repeat the one CPU device,
+    # enough to build the Mesh object and read its axis types
+    many = jax.devices() * 512
+    meshes = [mesh_lib.make_production_mesh(devices=many),
+              mesh_lib.make_production_mesh(multi_pod=True, devices=many),
+              mesh_lib.make_mesh((1, 1), ("data", "model")),
+              mesh_lib.make_host_mesh()]
+    for m in meshes:
+        assert set(m.axis_types) == {AxisType.Auto}, m
+    for m in meshes[2:]:
+        with use_rules(ShardingRules(m)):
+            y = jax.jit(lambda x: constrain(x * 2, "batch", "embed"))(
+                jnp.ones((4, 8)))
+        assert float(y.sum()) == 64.0

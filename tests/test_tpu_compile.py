@@ -1,0 +1,101 @@
+"""Compile-only checks for TPU v5e: the four Pallas kernels (Mosaic,
+interpret=False) and the full-width mamba2-130m train step, compiled for
+a described ``v5e:2x2`` topology.  Nothing runs; this catches what the
+chip's compiler refuses (block tiling, VMEM, HBM) without a chip.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library."""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.base import RunConfig, ShapeConfig, get_config
+from repro.kernels.cross_entropy import cross_entropy_pallas
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.rmsnorm import rmsnorm_pallas
+from repro.kernels.ssd_scan import ssd_pallas
+from repro.launch.mesh import make_mesh
+from repro.models import registry
+from repro.sharding import ShardingRules, batch_shardings, use_rules
+from repro.train.step import abstract_state, make_train_step, state_shardings
+
+V5E_HBM_BYTES = 16 * 2**30 * 0.984   # 15.75 GiB usable per v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # compiles for a described chip can be written to the persistent
+    # cache but never read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+# (name, kernel at interpret=False, argument shapes and dtypes); widths of
+# mamba2-130m (d_model 768, 24 SSD heads of 64, state 128, vocab 50280,
+# 16384 tokens per step) and a 20-head, 128-dim, 4096-token attention.
+KERNELS = [
+    ("rmsnorm", lambda x, w: rmsnorm_pallas(x, w, interpret=False),
+     [((16384, 768), BF), ((768,), BF)]),
+    ("flash_attention",
+     lambda q, k, v: flash_attention_pallas(q, k, v, interpret=False),
+     [((1, 4096, 20, 128), BF)] * 3),
+    ("ssd", lambda *a: ssd_pallas(*a, chunk=128, interpret=False),
+     [((8, 2048, 24, 64), BF), ((8, 2048, 24), F32), ((24,), F32),
+      ((8, 2048, 1, 128), BF), ((8, 2048, 1, 128), BF)]),
+    ("cross_entropy", lambda *a: cross_entropy_pallas(*a, interpret=False),
+     [((16384, 768), BF), ((50280, 768), BF), ((16384,), I32)]),
+]
+
+
+@pytest.mark.parametrize("name,fn,shapes", KERNELS,
+                         ids=[k[0] for k in KERNELS])
+def test_kernel_compiles_for_v5e(one_chip, name, fn, shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def test_mamba2_130m_train_step_compiles_for_one_v5e(topo):
+    """The step run_training builds, at published widths and depth (24
+    layers, seq 2048, batch 8), on a one-chip mesh of the described
+    topology; it must fit one chip's HBM."""
+    cfg = get_config("mamba2-130m")
+    run = RunConfig(total_steps=10, warmup_steps=2,
+                    ce_block_v=cfg.vocab_size // 8)
+    rules = ShardingRules(make_mesh((1, 1), ("data", "model"),
+                                    devices=topo.devices[:1]))
+    st_sh = state_shardings(cfg, rules)
+    with_sharding = lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                                       sharding=sh)
+    state = jax.tree.map(with_sharding, abstract_state(cfg, run), st_sh)
+    batch = registry.train_input_specs(cfg, ShapeConfig("t", 2048, 8,
+                                                        "train"))
+    batch = jax.tree.map(with_sharding, batch, batch_shardings(rules, batch))
+    with use_rules(rules):
+        compiled = jax.jit(make_train_step(cfg, run),
+                           out_shardings=(st_sh, None),
+                           donate_argnums=(0,)).lower(state, batch).compile()
+    mem = compiled.memory_analysis()
+    assert 0 < mem.peak_memory_in_bytes < V5E_HBM_BYTES
