@@ -1,0 +1,12 @@
+"""Makes ``chipbench`` and the program (``src/``) importable.
+
+    JAX_PLATFORMS=cpu python -m pytest chipbench/tests
+"""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (ROOT, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
