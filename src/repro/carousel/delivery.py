@@ -16,10 +16,16 @@ monotonic clock.
 
 Consumed rows are released from the DiskCache promptly (pin/release per
 shard), keeping the disk footprint at O(open shards), not O(dataset).
+
+Where JAX is loaded, the consumer thread's waits for a shard to land
+(``carousel.shard_wait``) and the assembly of each batch
+(``carousel.assemble``) are host spans on the profiler's clock.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
+import sys
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
@@ -27,6 +33,14 @@ import numpy as np
 
 from repro.carousel.stager import Stager
 from repro.carousel.storage import DiskCache
+
+
+def _span(name: str):
+    """A ``jax.profiler.TraceAnnotation`` where JAX is loaded (no profiler
+    can be tracing otherwise): the carousel itself does not import JAX."""
+    jax = sys.modules.get("jax")
+    return (jax.profiler.TraceAnnotation(name) if jax is not None
+            else contextlib.nullcontext())
 
 
 class DeliveryIterator:
@@ -63,7 +77,9 @@ class DeliveryIterator:
         deadline = time.monotonic() + self.timeout
         if self.coarse:
             # baseline: wait for the ENTIRE collection before any delivery
-            if not self.stager.wait(timeout=self.timeout):
+            with _span("carousel.shard_wait"):
+                staged = self.stager.wait(timeout=self.timeout)
+            if not staged:
                 raise TimeoutError("coarse staging timed out")
             failed = set(self.stager.failed()) & remaining
             if failed:
@@ -93,7 +109,8 @@ class DeliveryIterator:
                     raise TimeoutError(
                         "fine staging timed out; missing "
                         f"{sorted(remaining)[:5]}")
-                time.sleep(0.002)
+                with _span("carousel.shard_wait"):
+                    time.sleep(0.002)
 
     # -- batch assembly -------------------------------------------------------
     def __iter__(self) -> Iterator[Dict[str, Any]]:
@@ -124,22 +141,24 @@ class DeliveryIterator:
             self.cache.release(name, drop=True)  # prompt release
 
             while n_rows >= self.batch_rows:
-                batch = {k: np.concatenate(v) for k, v in rows.items()}
-                head = {k: v[:self.batch_rows] for k, v in batch.items()}
-                tail = {k: v[self.batch_rows:] for k, v in batch.items()}
-                rows = collections.defaultdict(list)
-                for k, v in tail.items():
-                    if v.shape[0]:
-                        rows[k].append(v)
-                n_rows -= self.batch_rows
-                self.rows_delivered += self.batch_rows
+                with _span("carousel.assemble"):
+                    batch = {k: np.concatenate(v) for k, v in rows.items()}
+                    head = {k: v[:self.batch_rows] for k, v in batch.items()}
+                    tail = {k: v[self.batch_rows:] for k, v in batch.items()}
+                    rows = collections.defaultdict(list)
+                    for k, v in tail.items():
+                        if v.shape[0]:
+                            rows[k].append(v)
+                    n_rows -= self.batch_rows
+                    self.rows_delivered += self.batch_rows
                 emit(head)
                 yield from drain()
         if n_rows > 0:
             # the final partial batch: without this, delivered rows !=
             # dataset rows whenever the dataset isn't a multiple of
             # batch_rows
-            batch = {k: np.concatenate(v) for k, v in rows.items()}
+            with _span("carousel.assemble"):
+                batch = {k: np.concatenate(v) for k, v in rows.items()}
             self.rows_delivered += n_rows
             emit(batch)
         yield from drain(force=True)

@@ -5,12 +5,58 @@ The model zoo calls these entry points exclusively.  ``use_pallas=False``
 ``use_pallas=True`` routes to the Pallas kernels, which Mosaic compiles on
 the TPU and which run in interpret mode where the default backend is the
 CPU (``kernels.resolve_interpret``).
+
+Each entry point runs under a ``jax.named_scope`` of ``SCOPES``, so that
+the reference and the kernel carry the same name in the compiled
+program's ``op_name`` metadata, which a profiler trace shows as ``tf_op``.
 """
 from __future__ import annotations
 
+import functools
+
+import jax
+
 from repro.kernels import ref as _ref
 
+SCOPES = ("embed", "norm", "proj", "conv", "ssd", "attention", "mlp",
+          "logits_ce", "adamw")
+"""The ``jax.named_scope`` names on the layers of the train step.  A scope
+names ops and changes nothing else in the compiled program.
 
+* ``embed``: ``models.layers.embed`` (the token lookup).
+* ``norm``: ``rmsnorm`` here, so every RMSNorm; in ``models.mamba2`` also
+  the gate ``y * silu(z)`` of the gated norm.
+* ``proj``: the six projections of ``models.mamba2.block_fwd`` (``w_z``,
+  ``w_x``, ``w_B``, ``w_C``, ``w_dt``, ``w_out``).
+* ``conv``: ``models.mamba2._causal_conv``.
+* ``ssd``: ``ssd`` and ``ssd_decode`` here; in ``models.mamba2`` also the
+  skip term ``D * x`` added to their output.
+* ``attention``: ``flash_attention`` here.
+* ``mlp``: ``models.layers.mlp`` and ``models.layers.moe_block``.
+* ``logits_ce``: the logits and cross entropy of ``train.loss.lm_loss``.
+* ``adamw``: ``optim.adamw.adamw_update`` (clipping and the update).
+
+Under ``jax.grad`` and ``jax.checkpoint`` an op's path reads ``jvp(...)``
+in the forward pass, ``transpose(jvp(...))`` in the backward pass, and
+``.../rematted_computation/...`` in the recomputed forward pass.
+"""
+
+
+def scoped(name: str):
+    """Decorator: run the function under ``jax.named_scope(name)``, a name
+    of ``SCOPES``."""
+    assert name in SCOPES, name
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
+@scoped("norm")
 def rmsnorm(x, w, *, eps: float = 1e-6, use_pallas: bool = False):
     if use_pallas:
         from repro.kernels.rmsnorm import rmsnorm_pallas
@@ -18,6 +64,7 @@ def rmsnorm(x, w, *, eps: float = 1e-6, use_pallas: bool = False):
     return _ref.rmsnorm_ref(x, w, eps)
 
 
+@scoped("attention")
 def flash_attention(q, k, v, *, causal: bool = True, q_offset=0, kv_len=None,
                     sliding_window: int = 0, block_k: int = 512,
                     use_pallas: bool = False, carry_constrain=None,
@@ -33,6 +80,7 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset=0, kv_len=None,
         carry_constrain=carry_constrain, custom_vjp=custom_vjp)
 
 
+@scoped("ssd")
 def ssd(x, dt, A, Bm, Cm, *, chunk: int = 128, init_state=None,
         return_state: bool = False, use_pallas: bool = False):
     if use_pallas:
@@ -43,6 +91,7 @@ def ssd(x, dt, A, Bm, Cm, *, chunk: int = 128, init_state=None,
                         init_state=init_state, return_state=return_state)
 
 
+@scoped("ssd")
 def ssd_decode(x, dt, A, Bm, Cm, h):
     """Single-token SSD recurrence (decode fast path)."""
     return _ref.ssd_decode_ref(x, dt, A, Bm, Cm, h)

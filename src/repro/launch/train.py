@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.carousel.delivery import DeliveryIterator
@@ -128,30 +129,42 @@ def run_training(
 
     losses: List[float] = []
     step_s: List[float] = []
-    t0 = time.time()
+    t0 = time.perf_counter()
     ttfb = None
+    batches = iter(batches)
     with use_rules(rules):
         done = start_step
-        for batch in batches:
-            if done >= start_step + steps:
-                break
-            t_step = time.time()
-            batch = jax.device_put(batch, batch_shardings(rules, batch))
-            state, metrics = step_fn(state, batch)
-            loss = float(metrics["loss"])
-            step_s.append(time.time() - t_step)
-            if ttfb is None:
-                ttfb = time.time() - t0
-            losses.append(loss)
-            done += 1
-            if on_step:
-                on_step(done, {"loss": loss})
-            if ckpt and done % ckpt_every == 0:
-                ckpt.save(state, done, meta={"loss": loss, "arch": arch})
+        while True:
+            with StepTraceAnnotation("train", step_num=done):
+                with TraceAnnotation("train.next_batch"):
+                    batch = next(batches, None)
+                if batch is None or done >= start_step + steps:
+                    break
+                t_step = time.perf_counter()
+                with TraceAnnotation("train.device_put"):
+                    batch = jax.device_put(batch,
+                                           batch_shardings(rules, batch))
+                with TraceAnnotation("train.dispatch"):
+                    state, metrics = step_fn(state, batch)
+                with TraceAnnotation("train.loss_read"):
+                    loss = float(metrics["loss"])
+                step_s.append(time.perf_counter() - t_step)
+                if ttfb is None:
+                    ttfb = time.perf_counter() - t0
+                losses.append(loss)
+                done += 1
+                if on_step:
+                    with TraceAnnotation("train.on_step"):
+                        on_step(done, {"loss": loss})
+                if ckpt and done % ckpt_every == 0:
+                    with TraceAnnotation("train.checkpoint"):
+                        ckpt.save(state, done,
+                                  meta={"loss": loss, "arch": arch})
     if ckpt:
-        ckpt.save(state, done, meta={"loss": losses[-1] if losses else None,
-                                     "arch": arch})
-        ckpt.close()
+        with TraceAnnotation("train.checkpoint"):
+            ckpt.save(state, done, meta={
+                "loss": losses[-1] if losses else None, "arch": arch})
+            ckpt.close()
     if stager:
         stager.shutdown()
     return {
@@ -162,7 +175,7 @@ def run_training(
         "losses": losses,
         "step_s": step_s,
         "time_to_first_batch_s": ttfb,
-        "wall_s": time.time() - t0,
+        "wall_s": time.perf_counter() - t0,
         "final_step": done,
         "state": state,
     }

@@ -349,6 +349,7 @@ def attention(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+@ops.scoped("mlp")
 def mlp(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
         act: Optional[str] = None) -> jax.Array:
     a = act_fn(act or cfg.act)
@@ -367,6 +368,7 @@ def mlp(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
     return constrain(y, "batch", None, None)
 
 
+@ops.scoped("mlp")
 def moe_block(p: Params, cfg: ModelConfig, run: RunConfig,
               x: jax.Array) -> jax.Array:
     """Top-k MoE dispatch. Two implementations:
@@ -525,6 +527,7 @@ def embed_defs(cfg: ModelConfig):
     return out
 
 
+@ops.scoped("embed")
 def embed(p: Params, tokens: jax.Array) -> jax.Array:
     y = jnp.take(p["tok"], tokens, axis=0)
     return constrain(y, "batch", None, None)

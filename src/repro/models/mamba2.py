@@ -65,6 +65,7 @@ def param_defs(cfg: ModelConfig) -> Params:
     }
 
 
+@ops.scoped("conv")
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
                  tail: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, jax.Array]:
@@ -93,11 +94,12 @@ def block_fwd(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
     N, H, P = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
     h = L.rmsnorm(p["ln"], x, cfg, run)
 
-    z = constrain(h @ p["w_z"], "batch", None, "ffn")
-    xs = constrain(h @ p["w_x"], "batch", None, "ffn")
-    Bm = h @ p["w_B"]
-    Cm = h @ p["w_C"]
-    dt = h @ p["w_dt"]
+    with jax.named_scope("proj"):
+        z = constrain(h @ p["w_z"], "batch", None, "ffn")
+        xs = constrain(h @ p["w_x"], "batch", None, "ffn")
+        Bm = h @ p["w_B"]
+        Cm = h @ p["w_C"]
+        dt = h @ p["w_dt"]
 
     tails = (None, None, None) if state is None else (
         state["tail_x"], state["tail_B"], state["tail_C"])
@@ -125,14 +127,17 @@ def block_fwd(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
         y, new_ssm = ops.ssd(xh, dtp, A, Bg, Cg, chunk=cfg.ssm_chunk,
                              init_state=init, return_state=True,
                              use_pallas=run.use_pallas)
-    y = y + (xh.astype(jnp.float32)
-             * p["D"][None, None, :, None]).astype(y.dtype)
+    with jax.named_scope("ssd"):
+        y = y + (xh.astype(jnp.float32)
+                 * p["D"][None, None, :, None]).astype(y.dtype)
     y = constrain(y, "batch", None, "heads_ssm", "ssm_p")
     y = y.reshape(Bb, S, H * P)
 
-    y = ops.rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                    p["norm"], eps=cfg.norm_eps, use_pallas=run.use_pallas)
-    out = constrain(y @ p["w_out"], "batch", None, None)
+    with jax.named_scope("norm"):
+        y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
+    y = ops.rmsnorm(y, p["norm"], eps=cfg.norm_eps, use_pallas=run.use_pallas)
+    with jax.named_scope("proj"):
+        out = constrain(y @ p["w_out"], "batch", None, None)
     new_state = None
     if state is not None:
         new_state = {"tail_x": tx, "tail_B": tb, "tail_C": tc,

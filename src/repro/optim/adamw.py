@@ -13,6 +13,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ops import scoped
+
 OptState = Dict[str, Any]
 
 
@@ -39,6 +41,7 @@ def adamw_init(params: Any, *, dtype=jnp.float32) -> OptState:
     }
 
 
+@scoped("adamw")
 def adamw_update(
     params: Any,
     grads: Any,

@@ -166,11 +166,12 @@ def lm_loss(params, cfg: ModelConfig, run: RunConfig,
     valid = valid.reshape(B * S) if valid is not None else None
     w = L.lm_head_weight(params["embed"], cfg)
 
-    if run.ce_mode == "blockwise":
-        loss = ce_blockwise(hidden, w, targets, valid, run.ce_block_v,
-                            jnp.dtype(run.ce_dtype))
-    else:
-        loss = ce_direct(hidden, w, targets, valid)
+    with jax.named_scope("logits_ce"):
+        if run.ce_mode == "blockwise":
+            loss = ce_blockwise(hidden, w, targets, valid, run.ce_block_v,
+                                jnp.dtype(run.ce_dtype))
+        else:
+            loss = ce_direct(hidden, w, targets, valid)
     ntok = (valid.sum() if valid is not None
             else jnp.asarray(B * S, jnp.float32))
     return loss, {"loss": loss, "tokens": ntok}

@@ -6,6 +6,7 @@ chip's compiler refuses (block tiling, VMEM, HBM) without a chip.
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.base import RunConfig, ShapeConfig, get_config
 from repro.kernels.cross_entropy import cross_entropy_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.ops import SCOPES
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_pallas
 from repro.launch.mesh import make_mesh
@@ -77,10 +79,11 @@ def test_kernel_compiles_for_v5e(one_chip, name, fn, shapes):
     assert "tpu_custom_call" in compiled.as_text(), name
 
 
-def test_mamba2_130m_train_step_compiles_for_one_v5e(topo):
+@pytest.fixture(scope="module")
+def mamba2_step(topo):
     """The step run_training builds, at published widths and depth (24
-    layers, seq 2048, batch 8), on a one-chip mesh of the described
-    topology; it must fit one chip's HBM."""
+    layers, seq 2048, batch 8), compiled on a one-chip mesh of the
+    described topology."""
     cfg = get_config("mamba2-130m")
     run = RunConfig(total_steps=10, warmup_steps=2,
                     ce_block_v=cfg.vocab_size // 8)
@@ -94,8 +97,23 @@ def test_mamba2_130m_train_step_compiles_for_one_v5e(topo):
                                                         "train"))
     batch = jax.tree.map(with_sharding, batch, batch_shardings(rules, batch))
     with use_rules(rules):
-        compiled = jax.jit(make_train_step(cfg, run),
-                           out_shardings=(st_sh, None),
-                           donate_argnums=(0,)).lower(state, batch).compile()
-    mem = compiled.memory_analysis()
+        return jax.jit(make_train_step(cfg, run),
+                       out_shardings=(st_sh, None),
+                       donate_argnums=(0,)).lower(state, batch).compile()
+
+
+def test_mamba2_130m_train_step_compiles_for_one_v5e(mamba2_step):
+    """The step fits one chip's HBM."""
+    mem = mamba2_step.memory_analysis()
     assert 0 < mem.peak_memory_in_bytes < V5E_HBM_BYTES
+
+
+def test_mamba2_130m_train_step_matmuls_are_scoped(mamba2_step):
+    """Every matrix product of the chip's program (a convolution on the
+    TPU) names a scope of ``SCOPES`` in its ``op_name``."""
+    convs = [ln for ln in mamba2_step.as_text().splitlines()
+             if re.search(r" convolution\(", ln)]
+    assert convs
+    for ln in convs:
+        m = re.search(r'op_name="([^"]*)"', ln)
+        assert m and set(re.split(r"[/()]", m.group(1))) & set(SCOPES), ln
