@@ -334,12 +334,17 @@ def ssd_ref(
     """Chunked SSD: y[t] = C[t] . h[t],
     h[t] = exp(dt[t] A) h[t-1] + dt[t] B[t] x[t].
 
-    Heads H are grouped over G B/C groups (H % G == 0).
+    Heads H are grouped over G B/C groups (H % G == 0) and kept as a
+    (G, K = H/G) pair of axes: B and C are never repeated over the K
+    heads of a group. Every contraction is one two-operand dot, with the
+    per-position scale factors folded into one operand first; a product
+    of more operands is lowered by XLA as an elementwise loop, not on the
+    matrix unit.
     """
     B_, S, H, P = x.shape
     _, _, G, N = Bm.shape
     assert H % G == 0
-    HG = H // G
+    K = H // G
     pad = (-S) % chunk
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -350,54 +355,52 @@ def ssd_ref(
     C_ = Sp // chunk
 
     f32 = jnp.float32
-    xc = x.astype(f32).reshape(B_, C_, chunk, H, P)
-    dtc = dt.astype(f32).reshape(B_, C_, chunk, H)
+    xc = x.astype(f32).reshape(B_, C_, chunk, G, K, P)
+    dtc = dt.astype(f32).reshape(B_, C_, chunk, G, K)
     Bc = Bm.astype(f32).reshape(B_, C_, chunk, G, N)
     Cc = Cm.astype(f32).reshape(B_, C_, chunk, G, N)
-    Af = A.astype(f32)
+    Af = A.astype(f32).reshape(G, K)
 
-    dA = dtc * Af[None, None, None, :]            # (B, C, Q, H)
+    dA = dtc * Af                                 # (B, C, Q, G, K)
     dA_cs = jnp.cumsum(dA, axis=2)                # cumulative within chunk
+    xdt = xc * dtc[..., None]                     # (B, C, Q, G, K, P)
 
     # ---- intra-chunk (diagonal blocks) ----
-    L = jnp.exp(_segsum(dA.transpose(0, 1, 3, 2)))  # (B, C, H, Q, Q)
+    L = jnp.exp(_segsum(dA.transpose(0, 1, 3, 4, 2)))  # (B, C, G, K, Q, Q)
     # scores: C[l] . B[s] per head group
     CB = jnp.einsum("bclgn,bcsgn->bcgls", Cc, Bc)   # (B, C, G, Q, Q)
-    CB = jnp.repeat(CB, HG, axis=2)                  # (B, C, H, Q, Q)
-    M = CB * L                                       # decay-weighted
-    y_intra = jnp.einsum("bchls,bcsh,bcshp->bclhp", M, dtc, xc)
+    M = CB[:, :, :, None] * L                        # decay-weighted
+    y_intra = jnp.einsum("bcgkls,bcsgkp->bclgkp", M, xdt)
 
     # ---- chunk states ----
-    decay_to_end = jnp.exp(dA_cs[:, :, -1:, :] - dA_cs)  # (B, C, Q, H)
-    Br = jnp.repeat(Bc, HG, axis=3)                       # (B, C, Q, H, N)
-    states = jnp.einsum("bcshn,bcsh,bcsh,bcshp->bchpn",
-                        Br, decay_to_end, dtc, xc)
+    decay_to_end = jnp.exp(dA_cs[:, :, -1:] - dA_cs)  # (B, C, Q, G, K)
+    states = jnp.einsum("bcsgn,bcsgkp->bcgkpn",
+                        Bc, xdt * decay_to_end[..., None])
 
     # ---- inter-chunk recurrence ----
-    chunk_decay = jnp.exp(jnp.sum(dA, axis=2))  # (B, C, H)
+    chunk_decay = jnp.exp(jnp.sum(dA, axis=2))  # (B, C, G, K)
 
     def scan_fn(h, inp):
-        st, dec = inp  # st: (B, H, P, N), dec: (B, H)
-        h_new = h * dec[:, :, None, None] + st
+        st, dec = inp  # st: (B, G, K, P, N), dec: (B, G, K)
+        h_new = h * dec[..., None, None] + st
         return h_new, h
 
-    h0 = (jnp.zeros((B_, H, P, N), f32) if init_state is None
-          else init_state.astype(f32))
-    states_t = states.transpose(1, 0, 2, 3, 4)        # (C, B, H, P, N)
-    decay_t = chunk_decay.transpose(1, 0, 2)          # (C, B, H)
+    h0 = (jnp.zeros((B_, G, K, P, N), f32) if init_state is None
+          else init_state.astype(f32).reshape(B_, G, K, P, N))
+    states_t = jnp.moveaxis(states, 1, 0)             # (C, B, G, K, P, N)
+    decay_t = jnp.moveaxis(chunk_decay, 1, 0)         # (C, B, G, K)
     h_last, h_prev = lax.scan(scan_fn, h0, (states_t, decay_t))
-    # (B, C, H, P, N) state BEFORE chunk
-    h_prev = h_prev.transpose(1, 0, 2, 3, 4)
+    # (B, C, G, K, P, N) state BEFORE chunk
+    h_prev = jnp.moveaxis(h_prev, 0, 1)
 
     # ---- inter-chunk output ----
-    in_decay = jnp.exp(dA_cs)                         # (B, C, Q, H)
-    Cr = jnp.repeat(Cc, HG, axis=3)                   # (B, C, Q, H, N)
-    y_inter = jnp.einsum("bclhn,bclh,bchpn->bclhp", Cr, in_decay, h_prev)
+    y_inter = (jnp.einsum("bclgn,bcgkpn->bclgkp", Cc, h_prev)
+               * jnp.exp(dA_cs)[..., None])
 
     y = (y_intra + y_inter).reshape(B_, Sp, H, P)[:, :S]
     y = y.astype(x.dtype)
     if return_state:
-        return y, h_last.astype(f32)
+        return y, h_last.reshape(B_, H, P, N)
     return y
 
 

@@ -204,6 +204,47 @@ def test_ssd_state_chaining_equals_decode():
     np.testing.assert_allclose(y_full[:, -1], y_dec, rtol=2e-4, atol=2e-4)
 
 
+SSD_GRAD_CASES = [
+    # B, S, H, P, G, N, chunk, init_state
+    (2, 16, 4, 8, 1, 16, 8, False),
+    (2, 16, 4, 8, 2, 16, 4, False),
+    (1, 13, 6, 8, 1, 16, 4, True),     # ragged tail
+    (1, 13, 4, 8, 2, 16, 8, True),     # ragged tail
+    (2, 16, 4, 8, 2, 8, 8, True),
+]
+
+
+@pytest.mark.parametrize("case", SSD_GRAD_CASES)
+def test_ssd_ref_grad_matches_sequential(case):
+    """The chunked scan's backward pass: gradients of a loss on the output
+    and on the returned state, for every input, against autodiff through
+    the token-by-token recurrence."""
+    B, S, H, P, G, N, chunk, with_init = case
+    rng = np.random.default_rng(7)
+    normal = lambda *shape: rng.standard_normal(shape, np.float32)
+    x = normal(B, S, H, P) * 0.5
+    dt = np.log1p(np.exp(normal(B, S, H)))
+    A = -np.exp(normal(H) * 0.3)
+    Bm, Cm = normal(B, S, G, N) * 0.3, normal(B, S, G, N) * 0.3
+    h0 = normal(B, H, P, N) * 0.3
+    wy, wh = normal(B, S, H, P), normal(B, H, P, N)
+
+    def loss(fn, x, dt, A, Bm, Cm, h0):
+        y, h = fn(x, dt, A, Bm, Cm, init_state=h0 if with_init else None)
+        return jnp.sum(y * wy) + jnp.sum(h * wh)
+
+    chunked = lambda *a, **k: ssd_ref(*a, chunk=chunk, return_state=True,
+                                      **k)
+    argnums = (1, 2, 3, 4, 5) + ((6,) if with_init else ())
+    grad = lambda fn: jax.jit(jax.grad(loss, argnums), static_argnums=0)(
+        fn, x, dt, A, Bm, Cm, h0)
+    g1, g2 = grad(chunked), grad(ssd_sequential_ref)
+    for name, a, b in zip(("x", "dt", "A", "B", "C", "init_state"), g1, g2):
+        scale = float(np.max(np.abs(b)))
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4 * scale,
+                                   err_msg=name)
+
+
 @settings(max_examples=15, deadline=None)
 @given(s=st.integers(2, 70), chunk=st.sampled_from([8, 16, 32]),
        seed=st.integers(0, 2**30))

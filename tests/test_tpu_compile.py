@@ -1,7 +1,8 @@
 """Compile-only checks for TPU v5e: the four Pallas kernels (Mosaic,
-interpret=False) and the full-width mamba2-130m train step, compiled for
-a described ``v5e:2x2`` topology.  Nothing runs; this catches what the
-chip's compiler refuses (block tiling, VMEM, HBM) without a chip.
+interpret=False), the full-width mamba2-130m train step and the XLA SSD
+path at the training cell's widths, compiled for a described ``v5e:2x2``
+topology.  Nothing runs; this catches what the chip's compiler refuses
+(block tiling, VMEM, HBM) or lowers off the matrix unit, without a chip.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library."""
@@ -17,6 +18,7 @@ from repro.configs.base import RunConfig, ShapeConfig, get_config
 from repro.kernels.cross_entropy import cross_entropy_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.ops import SCOPES
+from repro.kernels.ref import ssd_ref
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_pallas
 from repro.launch.mesh import make_mesh
@@ -117,3 +119,56 @@ def test_mamba2_130m_train_step_matmuls_are_scoped(mamba2_step):
     for ln in convs:
         m = re.search(r'op_name="([^"]*)"', ln)
         assert m and set(re.split(r"[/()]", m.group(1))) & set(SCOPES), ln
+
+
+# B, S, H, P, G, N, chunk of the training cell: mamba2-130m, 32 rows of
+# 2048 tokens, chunk 256
+SSD_CELL = (32, 2048, 24, 64, 1, 128, 256)
+
+
+@pytest.fixture(scope="module")
+def ssd_ref_grad_hlo(one_chip):
+    """The chip's program for the XLA SSD path (``ssd_ref``), forward and
+    backward, at the widths the training cell runs."""
+    B, S, H, P, G, N, chunk = SSD_CELL
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+            [((B, S, H, P), BF), ((B, S, H), F32), ((H,), F32),
+             ((B, S, G, N), BF), ((B, S, G, N), BF)]]
+
+    def loss(x, dt, A, Bm, Cm):
+        return jnp.sum(ssd_ref(x, dt, A, Bm, Cm, chunk=chunk).astype(F32))
+
+    grad = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    return jax.jit(grad).lower(*args).compile().as_text()
+
+
+def test_ssd_ref_contractions_are_not_loop_fusions(ssd_ref_grad_hlo):
+    """Every contraction of the chunk scan, forward and backward, is a
+    matrix-unit dot: no elementwise loop fusion is an einsum's
+    ``dot_general``."""
+    loops = [ln for ln in ssd_ref_grad_hlo.splitlines()
+             if "kind=kLoop" in ln
+             and re.search(r'op_name="[^"]*dot_general', ln)]
+    assert not loops, loops[0][:300]
+
+
+def test_ssd_ref_backward_sums_no_head_repeated_b_or_c(ssd_ref_grad_hlo):
+    """B and C are not repeated over the heads of their group: no reduction
+    sums a head axis away into a gradient shaped like B or C."""
+    B, S, H, P, G, N, chunk = SSD_CELL
+    hlo = ssd_ref_grad_hlo
+
+    def dims(shape):
+        """Sizes of an HLO shape without its unit axes, which XLA may
+        drop (G = 1)."""
+        return [int(d) for d in shape.split(",") if d not in ("", "1")]
+
+    shapes = {m[1]: m[2] for m in
+              re.finditer(r"(%[\w.-]+) = f32\[([\d,]*)\]", hlo)}
+    sums = re.finditer(r"= f32\[([\d,]*)\]\S* reduce\((%[\w.-]+), "
+                       r"[^)]*\), dimensions=\{([\d,]*)\}", hlo)
+    bc_shape = dims(f"{B},{S // chunk},{chunk},{G},{N}")
+    for m in sums:
+        operand = [int(d) for d in shapes[m[2]].split(",") if d]
+        summed = {operand[int(d)] for d in m[3].split(",")}
+        assert not (dims(m[1]) == bc_shape and H in summed), m[0]
