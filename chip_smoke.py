@@ -11,8 +11,9 @@ asynchronously, then resumes for 2 more; the serving driver prefills 512
 tokens and decodes 32 for 8 prompts; each Pallas kernel, compiled for the
 chip, is compared with its pure-jnp reference.
 
-Four chips: zamba2-1.2b at its published widths, whose training state
-(14.0 GB) does not fit one chip, trains 3 steps on a (4, 1) and on a
+Four chips: zamba2-1.2b on the Zamba2 hybrid block (some of its sizes
+unverified, see ``configs/zamba2_1_2b.py``), whose training state
+(14.2 GB) does not fit one chip, trains 3 steps on a (4, 1) and on a
 (2, 2) ("data", "model") mesh; the first-step losses must agree.
 
 Weights and data come from fixed seeds.  Everything runs in this one

@@ -6,6 +6,7 @@ from repro.configs import (  # noqa: F401
     starcoder2_15b,
     mamba2_130m,
     zamba2_1_2b,
+    zamba2_7b,
     qwen3_moe_235b,
     mixtral_8x7b,
     whisper_tiny,

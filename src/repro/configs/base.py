@@ -33,7 +33,7 @@ class ModelConfig:
     qkv_bias: bool = False
     mlp_bias: bool = False
     gated_mlp: bool = True  # SwiGLU-style (llama family); False -> plain MLP
-    act: str = "silu"  # silu | gelu
+    act: str = "silu"  # silu | gelu (exact, erf) | gelu_tanh
     rope_theta: float = 10_000.0
     sliding_window: int = 0  # 0 = full attention
 
@@ -48,10 +48,17 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_chunk: int = 128
     ssm_conv: int = 4
+    ssm_groups: int = 1  # B/C groups (mamba_ngroups); heads split evenly
 
-    # --- hybrid (zamba2-style): one attention block every `attn_every`
-    # ssm layers; 0 = not hybrid ---
-    attn_every: int = 0
+    # --- hybrid (Zamba2): before each Mamba2 layer of `hybrid_layer_ids`
+    # one of `num_mem_blocks` shared transformer blocks (used in turn) runs
+    # over [hidden, original embeddings]; its output, through a
+    # per-application linear map, joins that layer's input ---
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 1
+    adapter_rank: int = 0  # per-application LoRA on the MLP's gate/up; 0 none
+    attn_input_width: int = 0  # 0 -> d_model
+    attn_scale: float = 0.0  # softmax scale; 0 -> head_dim ** -0.5
 
     # --- encoder-decoder (whisper) ---
     encoder_layers: int = 0
@@ -74,8 +81,9 @@ class ModelConfig:
     def __post_init__(self) -> None:
         if self.head_dim == 0 and self.num_heads:
             self.head_dim = self.d_model // self.num_heads
+        self.hybrid_layer_ids = tuple(self.hybrid_layer_ids)
         if self.family == "ssm":
-            self.attn_every = 0
+            self.hybrid_layer_ids = ()
 
     # Derived quantities -----------------------------------------------------
     @property
@@ -85,6 +93,10 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def attn_in_dim(self) -> int:
+        return self.attn_input_width or self.d_model
 
     @property
     def ssm_inner(self) -> int:

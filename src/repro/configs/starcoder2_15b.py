@@ -1,5 +1,6 @@
 """starcoder2-15b — dense, 40L d_model=6144 48H (GQA kv=4) d_ff=24576
-vocab=49152, GQA + RoPE, non-gated GELU MLP with biases. [arXiv:2402.19173; hf]
+vocab=49152, GQA + RoPE, non-gated GELU MLP (tanh approximation,
+gelu_pytorch_tanh) with biases. [arXiv:2402.19173; hf]
 """
 from repro.configs.base import ModelConfig, register
 
@@ -16,7 +17,7 @@ CONFIG = ModelConfig(
     qkv_bias=True,
     mlp_bias=True,
     gated_mlp=False,
-    act="gelu",
+    act="gelu_tanh",
     rope_theta=100_000.0,
     norm_eps=1e-5,
     source="arXiv:2402.19173; hf",

@@ -1,15 +1,17 @@
-"""zamba2-1.2b — hybrid Mamba2 + shared attention blocks,
-38L d_model=2048 32H (GQA kv=32) d_ff=8192 vocab=32000 ssm_state=64.
-[arXiv:2411.15242; hf]
+"""zamba2-1.2b — Zyphra's Zamba2-1.2B, on the Zamba2 hybrid block of
+``models/hybrid.py``: 38 Mamba2 layers at d_model 2048 and one shared
+transformer block over [hidden, embeddings] (4096 wide, 32 heads of 128),
+vocab 32000.  [arXiv:2411.15242]
 
-Adaptation note (DESIGN.md §6): Zamba2 interleaves Mamba2 blocks with a
-*shared* attention block applied every ~6 layers over concatenated
-embeddings.  We realize the same compute/communication pattern as a
-hybrid stack: Mamba2 layers with one (weight-shared) attention+MLP block
-applied every `attn_every` SSM layers.  Runs long_500k: SSM state is O(1)
-and only the periodic attention blocks hold (sharded) 500k KV.
+Unverified (no config.json of this model was at hand; ``zamba2-7b``'s
+published settings stand in): the hybrid layers (every sixth), the
+adapter (rank 128 on the MLP only), one B/C group, chunk 128, state 64,
+an exact-GELU MLP of 8192, the softmax scale (head_dim / 2) ** -0.5 and
+tied embeddings.
 """
 from repro.configs.base import ModelConfig, register
+
+HEAD_DIM = 128
 
 CONFIG = ModelConfig(
     name="zamba2-1.2b",
@@ -18,7 +20,7 @@ CONFIG = ModelConfig(
     d_model=2048,
     num_heads=32,
     num_kv_heads=32,
-    head_dim=64,
+    head_dim=HEAD_DIM,
     d_ff=8192,
     vocab_size=32000,
     ssm_state=64,
@@ -26,12 +28,18 @@ CONFIG = ModelConfig(
     ssm_head_dim=64,
     ssm_chunk=128,
     ssm_conv=4,
-    attn_every=6,
+    hybrid_layer_ids=(6, 12, 18, 24, 30, 36),
+    num_mem_blocks=1,
+    adapter_rank=128,
+    attn_input_width=2 * 2048,
+    attn_scale=(HEAD_DIM / 2) ** -0.5,
     gated_mlp=True,
     act="gelu",
     rope_theta=10_000.0,
     norm_eps=1e-5,
-    source="arXiv:2411.15242; hf",
+    tie_embeddings=True,
+    source="arXiv:2411.15242; widths of the shared block and its "
+           "placement unverified",
 )
 
 SMOKE = CONFIG.replace(
@@ -40,13 +48,16 @@ SMOKE = CONFIG.replace(
     d_model=64,
     num_heads=4,
     num_kv_heads=4,
-    head_dim=16,
+    head_dim=32,
     d_ff=128,
     vocab_size=256,
     ssm_state=16,
     ssm_head_dim=16,
     ssm_chunk=32,
-    attn_every=2,
+    hybrid_layer_ids=(2,),
+    adapter_rank=8,
+    attn_input_width=128,
+    attn_scale=(32 / 2) ** -0.5,
 )
 
 register(CONFIG, SMOKE)

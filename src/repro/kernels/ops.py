@@ -19,7 +19,7 @@ import jax
 from repro.kernels import ref as _ref
 
 SCOPES = ("embed", "norm", "proj", "conv", "ssd", "attention", "mlp",
-          "logits_ce", "adamw")
+          "logits_ce", "adamw", "shared_block", "adapter")
 """The ``jax.named_scope`` names on the layers of the train step.  A scope
 names ops and changes nothing else in the compiled program.
 
@@ -31,10 +31,16 @@ names ops and changes nothing else in the compiled program.
 * ``conv``: ``models.mamba2._causal_conv``.
 * ``ssd``: ``ssd`` and ``ssd_decode`` here; in ``models.mamba2`` also the
   skip term ``D * x`` added to their output.
-* ``attention``: ``flash_attention`` here.
+* ``attention``: ``models.layers.attention`` (projections, and
+  ``flash_attention`` here).
 * ``mlp``: ``models.layers.mlp`` and ``models.layers.moe_block``.
 * ``logits_ce``: the logits and cross entropy of ``train.loss.lm_loss``.
 * ``adamw``: ``optim.adamw.adamw_update`` (clipping and the update).
+* ``shared_block``: a hybrid layer's shared transformer block in
+  ``models.hybrid``: the concatenation with the embeddings and the
+  per-application linear map (its norms, attention and MLP keep their
+  own scopes, which are innermost).
+* ``adapter``: the low-rank adapter products of ``models.layers.mlp``.
 
 Under ``jax.grad`` and ``jax.checkpoint`` an op's path reads ``jvp(...)``
 in the forward pass, ``transpose(jvp(...))`` in the backward pass, and
@@ -58,7 +64,11 @@ def scoped(name: str):
 
 @scoped("norm")
 def rmsnorm(x, w, *, eps: float = 1e-6, use_pallas: bool = False):
+    """Over the last axis of x; ``w`` has the shape of x's trailing axes
+    (one weight vector, or one per group of a grouped norm)."""
     if use_pallas:
+        if w.ndim != 1:
+            raise ValueError("the Pallas RMSNorm takes one weight vector")
         from repro.kernels.rmsnorm import rmsnorm_pallas
         return rmsnorm_pallas(x, w, eps=eps)
     return _ref.rmsnorm_ref(x, w, eps)
@@ -67,16 +77,17 @@ def rmsnorm(x, w, *, eps: float = 1e-6, use_pallas: bool = False):
 @scoped("attention")
 def flash_attention(q, k, v, *, causal: bool = True, q_offset=0, kv_len=None,
                     sliding_window: int = 0, block_k: int = 512,
-                    use_pallas: bool = False, carry_constrain=None,
-                    custom_vjp: bool = True):
+                    scale=None, use_pallas: bool = False,
+                    carry_constrain=None, custom_vjp: bool = True):
+    """``scale``: the softmax scale; None -> head_dim ** -0.5."""
     if use_pallas:
         from repro.kernels.flash_attention import flash_attention_pallas
         return flash_attention_pallas(
             q, k, v, causal=causal, q_offset=q_offset, kv_len=kv_len,
-            sliding_window=sliding_window)
+            sliding_window=sliding_window, scale=scale)
     return _ref.flash_attention_ref(
         q, k, v, causal=causal, q_offset=q_offset, kv_len=kv_len,
-        sliding_window=sliding_window, block_k=block_k,
+        sliding_window=sliding_window, block_k=block_k, scale=scale,
         carry_constrain=carry_constrain, custom_vjp=custom_vjp)
 
 

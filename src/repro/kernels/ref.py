@@ -66,7 +66,7 @@ def _rmsnorm_vjp_bwd(eps, res, g):
     xhat = xf * inv
     gw = gf * wf
     dx = inv * (gw - xhat * jnp.mean(gw * xhat, axis=-1, keepdims=True))
-    dw = jnp.sum(gf * xhat, axis=tuple(range(x.ndim - 1)))
+    dw = jnp.sum(gf * xhat, axis=tuple(range(x.ndim - w.ndim)))
     return dx.astype(x.dtype), dw.astype(w.dtype)
 
 

@@ -1,7 +1,19 @@
-"""Zamba2-style hybrid: Mamba2 backbone with a SHARED attention block
-applied every ``attn_every`` SSM layers (arXiv:2411.15242; see DESIGN.md
-adaptation note — per-application LoRA adapters are omitted, the shared
-attention+MLP block and its placement period are kept).
+"""Zamba2 hybrid (arXiv:2411.15242): a stack of Mamba2 layers, some of
+which (``cfg.hybrid_layer_ids``) are "hybrid".  Before its Mamba2 layer,
+the i-th hybrid layer runs shared transformer block ``i % num_mem_blocks``
+over the concatenation of the hidden states and the original token
+embeddings (width ``attn_input_width``), then its own ``linear`` map, and
+adds the result to the Mamba2 layer's input before that layer's norm (not
+to its residual).
+
+Shared block, as in Zamba2's ``Zamba2AttentionDecoderLayer``:
+``input_layernorm`` -> attention (``attn_input_width`` -> d_model, heads of
+``head_dim``, rope, softmax scale ``attn_scale``) -> ``pre_ff_layernorm``
+-> gated MLP whose gate and up projections get the hybrid layer's own
+low-rank adapter (rank ``adapter_rank``).  No residual inside the block.
+
+Training and prefill/decode run the same layers; serving keeps one KV
+cache per hybrid layer beside the Mamba2 states.
 """
 from __future__ import annotations
 
@@ -9,94 +21,89 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from repro.configs.base import ModelConfig, RunConfig
 from repro.models import layers as L
 from repro.models import mamba2 as M
+from repro.models.params import pdef
+from repro.sharding import constrain
 
 Params = Dict[str, Any]
 
 
-def n_attn_applications(cfg: ModelConfig) -> int:
-    return cfg.num_layers // cfg.attn_every
-
-
 def param_defs(cfg: ModelConfig) -> Params:
+    nb, n_app, d = cfg.num_mem_blocks, len(cfg.hybrid_layer_ids), cfg.d_model
+    hybrid: Params = {
+        "linear": pdef((n_app, d, d), ("layers", "embed", None),
+                       init="scaled"),
+    }
+    if cfg.adapter_rank:
+        hybrid["adapter"] = L.lora_defs(cfg, n_app)
     return {
         "embed": L.embed_defs(cfg),
         "blocks": M.block_defs(cfg, cfg.num_layers),
-        "shared_attn": {
-            "ln1": L.norm_defs(0, cfg.d_model),
-            "attn": L.attention_defs(cfg, 0),
-            "ln2": L.norm_defs(0, cfg.d_model),
-            "mlp": L.mlp_defs(cfg, 0),
+        "shared": {
+            "ln_in": L.norm_defs(nb, cfg.attn_in_dim),
+            "attn": L.attention_defs(cfg, nb),
+            "ln_ff": L.norm_defs(nb, d),
+            "mlp": L.mlp_defs(cfg, nb),
         },
-        "ln_f": L.norm_defs(0, cfg.d_model),
+        "hybrid": hybrid,
+        "ln_f": L.norm_defs(0, d),
     }
 
 
-def _shared_attn(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
-                 positions, cache_l, cache_pos, kv_len):
-    h = L.rmsnorm(p["ln1"], x, cfg, run)
-    h, new_cache = L.attention(p["attn"], cfg, run, h, positions=positions,
-                               cache=cache_l, cache_pos=cache_pos,
-                               kv_len=kv_len)
-    x = x + h
-    h = L.rmsnorm(p["ln2"], x, cfg, run)
-    return x + L.mlp(p["mlp"], cfg, run, h), new_cache
+def _take(tree, i: int):
+    return jax.tree.map(lambda a: a[i], tree)
+
+
+def _shared_block(sp: Params, hp: Params, cfg: ModelConfig, run: RunConfig,
+                  x, emb, positions, cache, cache_pos, kv_len):
+    """One hybrid layer's shared block and linear map: its contribution to
+    the Mamba2 layer's input, and the updated KV cache."""
+    with jax.named_scope("shared_block"):
+        h = jnp.concatenate([x, emb], axis=-1)
+        h = L.rmsnorm(sp["ln_in"], h, cfg, run)
+        h, new_cache = L.attention(sp["attn"], cfg, run, h,
+                                   positions=positions, cache=cache,
+                                   cache_pos=cache_pos, kv_len=kv_len)
+        h = L.rmsnorm(sp["ln_ff"], h, cfg, run)
+        h = L.mlp(sp["mlp"], cfg, run, h, lora=hp.get("adapter"))
+        out = constrain(h @ hp["linear"], "batch", None, None)
+    return out, new_cache
 
 
 def _run(params, cfg, run, x, positions, mamba_state=None, kv_cache=None,
          cache_pos=None, kv_len=None):
-    """Groups of `attn_every` scanned mamba layers + one shared-attn hit."""
-    k = cfg.attn_every
-    n_app = n_attn_applications(cfg)
-    rem = cfg.num_layers - n_app * k
+    """Every layer in order: runs of plain Mamba2 layers (scanned) and the
+    hybrid layers between them."""
+    emb = x
     blocks = params["blocks"]
+    shared = lambda sp, hp, *a: _shared_block(sp, hp, cfg, run, *a)
+    if run.remat != "none":
+        shared = jax.checkpoint(shared)
 
-    def mamba_body(carry, xs_):
-        h, p_l, s_l = carry, xs_[0], xs_[1]
-        fn = lambda p, hh, ss: M.block_fwd(p, cfg, run, hh, ss)
-        if run.remat != "none":
-            fn = jax.checkpoint(fn)
-        h, ns = fn(p_l, h, s_l)
-        return h, ns
-
-    def run_group(x, blk, st):
-        if run.scan_layers:
-            return lax.scan(mamba_body, x, (blk, st))
-        outs = []
-        nlayers = jax.tree.leaves(blk)[0].shape[0]
-        for i in range(nlayers):
-            p_l = jax.tree.map(lambda a: a[i], blk)
-            s_l = None if st is None else jax.tree.map(lambda a: a[i], st)
-            x, ns = mamba_body(x, (p_l, s_l))
-            outs.append(ns)
-        ns_all = (None if st is None
-                  else jax.tree.map(lambda *s: jnp.stack(s), *outs))
-        return x, ns_all
-
-    def group_slice(tree, lo, hi):
-        return jax.tree.map(lambda a: a[lo:hi], tree)
-
-    new_states, new_kv = [], []
-    for g in range(n_app):
-        blk = group_slice(blocks, g * k, (g + 1) * k)
+    def layers(x, lo, hi, extra=None):
         st = (None if mamba_state is None
-              else group_slice(mamba_state, g * k, (g + 1) * k))
-        x, ns = run_group(x, blk, st)
-        new_states.append(ns)
-        c_l = (None if kv_cache is None
-               else jax.tree.map(lambda a: a[g], kv_cache))
-        x, nc = _shared_attn(params["shared_attn"], cfg, run, x, positions,
-                             c_l, cache_pos, kv_len)
+              else jax.tree.map(lambda a: a[lo:hi], mamba_state))
+        return M.run_layers(jax.tree.map(lambda a: a[lo:hi], blocks), cfg,
+                            run, x, st, extra)
+
+    new_states, new_kv, lo = [], [], 0
+    for i, h_id in enumerate(cfg.hybrid_layer_ids):
+        if h_id > lo:
+            x, ns = layers(x, lo, h_id)
+            new_states.append(ns)
+        c_i = None if kv_cache is None else _take(kv_cache, i)
+        t, nc = shared(_take(params["shared"], i % cfg.num_mem_blocks),
+                       _take(params["hybrid"], i), x, emb, positions, c_i,
+                       cache_pos, kv_len)
         new_kv.append(nc)
-    if rem:
-        blk = group_slice(blocks, n_app * k, cfg.num_layers)
-        st = (None if mamba_state is None
-              else group_slice(mamba_state, n_app * k, cfg.num_layers))
-        x, ns = run_group(x, blk, st)
+        x, ns = layers(x, h_id, h_id + 1, extra=t)
+        new_states.append(ns)
+        lo = h_id + 1
+    if cfg.num_layers > lo:
+        x, ns = layers(x, lo, cfg.num_layers)
         new_states.append(ns)
 
     out_state = (None if mamba_state is None else
@@ -116,7 +123,8 @@ def forward(params, cfg, run, batch):
 def cache_defs(cfg: ModelConfig, batch: int, max_len: int) -> Params:
     return {
         "mamba": M.state_defs(cfg, cfg.num_layers, batch),
-        "kv": L.kv_cache_defs(cfg, n_attn_applications(cfg), batch, max_len),
+        "kv": L.kv_cache_defs(cfg, len(cfg.hybrid_layer_ids), batch,
+                              max_len),
     }
 
 

@@ -10,6 +10,7 @@ Conventions:
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -37,13 +38,14 @@ def norm_defs(L: int, d: int):
 
 
 def attention_defs(cfg: ModelConfig, L: int, *, cross: bool = False):
-    d, qd, kvd = cfg.d_model, cfg.q_dim, cfg.kv_dim
+    """Q/K/V read ``cfg.attn_in_dim`` features; the output is d_model."""
+    d, din, qd, kvd = cfg.d_model, cfg.attn_in_dim, cfg.q_dim, cfg.kv_dim
     lead = (L,) if L else ()
     ll = ("layers",) if L else ()
     out: Params = {
-        "wq": pdef(lead + (d, qd), ll + ("embed", "qkv"), init="scaled"),
-        "wk": pdef(lead + (d, kvd), ll + ("embed", "qkv"), init="scaled"),
-        "wv": pdef(lead + (d, kvd), ll + ("embed", "qkv"), init="scaled"),
+        "wq": pdef(lead + (din, qd), ll + ("embed", "qkv"), init="scaled"),
+        "wk": pdef(lead + (din, kvd), ll + ("embed", "qkv"), init="scaled"),
+        "wv": pdef(lead + (din, kvd), ll + ("embed", "qkv"), init="scaled"),
         "wo": pdef(lead + (qd, d), ll + ("qkv", "embed"), init="scaled"),
     }
     if cfg.qkv_bias:
@@ -69,6 +71,18 @@ def mlp_defs(cfg: ModelConfig, L: int, d_ff: Optional[int] = None):
         out["b_up"] = pdef(lead + (f,), ll + ("ffn",), init="zeros")
         out["b_down"] = pdef(lead + (d,), ll + (None,), init="zeros")
     return out
+
+
+def lora_defs(cfg: ModelConfig, L: int):
+    """Low-rank adapters of a gated MLP's gate and up projections, one per
+    layer: ``x @ a`` (rank ``cfg.adapter_rank``) times ``b_gate`` and
+    ``b_up``."""
+    d, r, f = cfg.d_model, cfg.adapter_rank, cfg.d_ff
+    return {
+        "a": pdef((L, d, r), ("layers", "embed", None), init="scaled"),
+        "b_gate": pdef((L, r, f), ("layers", "lora", "ffn"), init="scaled"),
+        "b_up": pdef((L, r, f), ("layers", "lora", "ffn"), init="scaled"),
+    }
 
 
 def moe_defs(cfg: ModelConfig, L: int):
@@ -97,8 +111,17 @@ def rmsnorm(p, x, cfg: ModelConfig, run: RunConfig):
     return ops.rmsnorm(x, p, eps=cfg.norm_eps, use_pallas=run.use_pallas)
 
 
+_ACTS = {
+    "silu": jax.nn.silu,
+    "gelu": functools.partial(jax.nn.gelu, approximate=False),
+    "gelu_tanh": functools.partial(jax.nn.gelu, approximate=True),
+}
+
+
 def act_fn(name: str):
-    return jax.nn.silu if name == "silu" else jax.nn.gelu
+    """``gelu`` is the exact (erf) GELU; ``gelu_tanh`` its tanh
+    approximation (``gelu_pytorch_tanh``)."""
+    return _ACTS[name]
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -252,6 +275,7 @@ def _attention_kvseq(q, k, v, *, causal, q_offset, kv_len, sliding_window,
     return out.astype(q.dtype)
 
 
+@ops.scoped("attention")
 def attention(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
               *, positions: jax.Array, causal: bool = True,
               cache: Optional[Params] = None, cache_pos=None,
@@ -260,7 +284,7 @@ def attention(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
               use_rope: bool = True) -> Tuple[jax.Array, Optional[Params]]:
     """General GQA attention with optional KV cache and cross-attention.
 
-    x: (B, S, d_model). xkv: encoder output for cross-attention.
+    x: (B, S, cfg.attn_in_dim). xkv: encoder output for cross-attention.
     cache: single-layer cache dict (already sliced out of the stack).
     cache_pos: scalar write offset into the cache.
     cache_read_only: cross-attention decode — use cached K/V, no update.
@@ -268,6 +292,7 @@ def attention(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
     """
     B, S, _ = x.shape
     Hq, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    scale = cfg.attn_scale or None
     src = x if xkv is None else xkv
 
     q = x @ p["wq"]
@@ -303,7 +328,7 @@ def attention(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
         # axis, partial softmax reduced across shards by GSPMD.
         out = _attention_kvseq(
             q, k, v, causal=causal, q_offset=q_offset,
-            kv_len=kv_len, sliding_window=cfg.sliding_window)
+            kv_len=kv_len, sliding_window=cfg.sliding_window, scale=scale)
     elif heads_ok:
         # TP over heads. For GQA, K/V are repeated up to Hq *after* the
         # head constraint so every intermediate carries a clean 16-way
@@ -319,7 +344,7 @@ def attention(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
         out = ops.flash_attention(
             q, k, v, causal=causal, q_offset=q_offset,
             kv_len=kv_len, sliding_window=cfg.sliding_window,
-            block_k=run.attn_block_k, use_pallas=run.use_pallas,
+            block_k=run.attn_block_k, scale=scale, use_pallas=run.use_pallas,
             custom_vjp=run.flash_custom_vjp,
             carry_constrain=lambda t: constrain(
                 t, *(("batch", None, "heads") + (None,) * (t.ndim - 3))))
@@ -333,7 +358,7 @@ def attention(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
         out = ops.flash_attention(
             q, k, v, causal=causal, q_offset=q_offset,
             kv_len=kv_len, sliding_window=cfg.sliding_window,
-            block_k=run.attn_block_k, use_pallas=run.use_pallas,
+            block_k=run.attn_block_k, scale=scale, use_pallas=run.use_pallas,
             custom_vjp=run.flash_custom_vjp,
             carry_constrain=lambda t: constrain(
                 t, *(("batch", "q_seq") + (None,) * (t.ndim - 2))))
@@ -351,15 +376,23 @@ def attention(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
 
 @ops.scoped("mlp")
 def mlp(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
-        act: Optional[str] = None) -> jax.Array:
+        act: Optional[str] = None,
+        lora: Optional[Params] = None) -> jax.Array:
+    """``lora``: one layer's ``lora_defs`` leaves, added to the gate and up
+    projections of a gated MLP."""
     a = act_fn(act or cfg.act)
     up = x @ p["w_up"]
     if "b_up" in p:
         up = up + p["b_up"]
+    gate = x @ p["w_gate"] if "w_gate" in p else None
+    if lora is not None:
+        with jax.named_scope("adapter"):
+            r = x @ lora["a"]
+            gate = gate + r @ lora["b_gate"]
+            up = up + r @ lora["b_up"]
     up = constrain(up, "batch", None, "ffn")
-    if "w_gate" in p:
-        gate = constrain(x @ p["w_gate"], "batch", None, "ffn")
-        h = a(gate) * up
+    if gate is not None:
+        h = a(constrain(gate, "batch", None, "ffn")) * up
     else:
         h = a(up)
     y = h @ p["w_down"]

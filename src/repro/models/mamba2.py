@@ -10,6 +10,10 @@ divides 16).  SSD head dim shards on the model axis iff divisible
 (zamba2: 64 heads -> sharded; mamba2-130m: 24 heads -> replicated inner
 scan, projections still sharded).
 
+B/C come in ``cfg.ssm_groups`` groups (Mamba2's ``ngroups``): head h reads
+group h // (H / G), and the gated RMSNorm normalises each group of
+d_inner / G channels on its own (Mamba2's ``RMSNormGated``).
+
 Decode state is O(1): conv tails (W-1 tokens) + SSM state (H, P, N).
 """
 from __future__ import annotations
@@ -27,12 +31,11 @@ from repro.models.params import pdef
 from repro.sharding import constrain
 
 Params = Dict[str, Any]
-G = 1  # number of B/C groups (mamba2 default ngroups=1)
 
 
 def block_defs(cfg: ModelConfig, n: int) -> Params:
     d, din = cfg.d_model, cfg.ssm_inner
-    N, H, W = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_conv
+    N, H, W, G = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_conv, cfg.ssm_groups
     lead, ll = ((n,), ("layers",)) if n else ((), ())
     return {
         "ln": L.norm_defs(n, d),
@@ -87,12 +90,15 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
 
 
 def block_fwd(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
-              state: Optional[Params] = None
+              state: Optional[Params] = None,
+              extra: Optional[jax.Array] = None
               ) -> Tuple[jax.Array, Optional[Params]]:
-    """x: (B, S, d). state (decode): conv tails + ssm state; None for train."""
+    """x: (B, S, d). state (decode): conv tails + ssm state; None for train.
+    extra: added to the layer's input before its norm, not to the residual
+    (a Zamba2 hybrid layer's shared-block output)."""
     Bb, S, _ = x.shape
-    N, H, P = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
-    h = L.rmsnorm(p["ln"], x, cfg, run)
+    N, H, P, G = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups
+    h = L.rmsnorm(p["ln"], x if extra is None else x + extra, cfg, run)
 
     with jax.named_scope("proj"):
         z = constrain(h @ p["w_z"], "batch", None, "ffn")
@@ -135,7 +141,11 @@ def block_fwd(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
 
     with jax.named_scope("norm"):
         y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
-    y = ops.rmsnorm(y, p["norm"], eps=cfg.norm_eps, use_pallas=run.use_pallas)
+    w_norm = p["norm"]
+    if G > 1:
+        y, w_norm = y.reshape(Bb, S, G, -1), w_norm.reshape(G, -1)
+    y = ops.rmsnorm(y, w_norm, eps=cfg.norm_eps, use_pallas=run.use_pallas)
+    y = y.reshape(Bb, S, H * P)
     with jax.named_scope("proj"):
         out = constrain(y @ p["w_out"], "batch", None, None)
     new_state = None
@@ -148,7 +158,7 @@ def block_fwd(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
 def state_defs(cfg: ModelConfig, n: int, batch: int) -> Params:
     """Decode-state ParamDefs for n stacked mamba blocks."""
     N, H, P, W = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_conv
-    din = cfg.ssm_inner
+    din, G = cfg.ssm_inner, cfg.ssm_groups
     lead, ll = ((n,), ("layers",)) if n else ((), ())
     return {
         "tail_x": pdef(lead + (batch, W - 1, din),
@@ -163,31 +173,32 @@ def state_defs(cfg: ModelConfig, n: int, batch: int) -> Params:
     }
 
 
-def _run_blocks(params, cfg, run, x, state=None):
+def run_layers(blocks, cfg, run, x, state=None, extra=None):
+    """The stacked layers of ``blocks`` in order (scanned, or unrolled);
+    ``extra`` as in ``block_fwd``, for every layer.  Returns the hidden
+    states and the stacked new states (None without ``state``)."""
+    fn = lambda p, hh, ss, ex: block_fwd(p, cfg, run, hh, ss, ex)
+    if run.remat != "none":
+        fn = jax.checkpoint(fn)
+
     def body(carry, xs_):
-        h = carry
         p_l, s_l = xs_
-        fn = lambda p, hh, ss: block_fwd(p, cfg, run, hh, ss)
-        if run.remat != "none":
-            fn = jax.checkpoint(fn)
-        h, new_s = fn(p_l, h, s_l)
-        return h, new_s
+        return fn(p_l, carry, s_l, extra)
 
     if run.scan_layers:
-        x, new_state = lax.scan(body, x, (params["blocks"], state))
-    else:
-        fn = lambda p, hh, ss: block_fwd(p, cfg, run, hh, ss)
-        if run.remat != "none":
-            fn = jax.checkpoint(fn)
-        outs = []
-        for i in range(cfg.num_layers):
-            p_l = jax.tree.map(lambda a: a[i], params["blocks"])
-            s_l = (None if state is None
-                   else jax.tree.map(lambda a: a[i], state))
-            x, ns = fn(p_l, x, s_l)
-            outs.append(ns)
-        new_state = (None if state is None
-                     else jax.tree.map(lambda *s: jnp.stack(s), *outs))
+        return lax.scan(body, x, (blocks, state))
+    outs = []
+    for i in range(jax.tree.leaves(blocks)[0].shape[0]):
+        p_l = jax.tree.map(lambda a: a[i], blocks)
+        s_l = None if state is None else jax.tree.map(lambda a: a[i], state)
+        x, ns = fn(p_l, x, s_l, extra)
+        outs.append(ns)
+    return x, (None if state is None
+               else jax.tree.map(lambda *s: jnp.stack(s), *outs))
+
+
+def _run_blocks(params, cfg, run, x, state=None):
+    x, new_state = run_layers(params["blocks"], cfg, run, x, state)
     return L.rmsnorm(params["ln_f"], x, cfg, run), new_state
 
 

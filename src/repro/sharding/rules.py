@@ -16,6 +16,9 @@ Default physical mapping:
   ffn     -> ("model",)          MLP hidden (TP)
   vocab   -> ("model",)          embedding/vocab rows (TP)
   expert  -> ("model",)          MoE experts (EP)
+  lora    -> ("data",)           an adapter's rank dim in its up-projection
+                                 (FSDP: the one dim of that weight that
+                                 is not tensor-parallel)
   kv_seq  -> ("model",)          KV sequence inside attention, ONLY for
                                  archs whose head count doesn't divide
                                  (flash-decoding-style partial softmax)
@@ -43,6 +46,7 @@ DEFAULT_RULES: Dict[str, Tuple[str, ...]] = {
     "ffn": ("model",),
     "vocab": ("model",),
     "expert": ("model",),
+    "lora": ("data",),
     "kv_seq": ("model",),
     "q_seq": ("model",),
     "layers": (),

@@ -18,9 +18,10 @@ RUN = RunConfig(total_steps=10, warmup_steps=2, ce_block_v=64)
 
 
 def test_all_archs_registered():
-    assert len(ARCHS) == 10
+    assert len(ARCHS) == 11
     expected = {"qwen1.5-32b", "yi-6b", "qwen1.5-4b", "starcoder2-15b",
-                "mamba2-130m", "zamba2-1.2b", "qwen3-moe-235b-a22b",
+                "mamba2-130m", "zamba2-1.2b", "zamba2-7b",
+                "qwen3-moe-235b-a22b",
                 "mixtral-8x7b", "whisper-tiny", "llava-next-mistral-7b"}
     assert set(ARCHS) == expected
 
@@ -59,7 +60,8 @@ def test_prefill_decode_smoke(arch):
     assert bool(jnp.all((tok2 >= 0) & (tok2 < cfg.vocab_size)))
 
 
-@pytest.mark.parametrize("arch", ["yi-6b", "mamba2-130m", "zamba2-1.2b"])
+@pytest.mark.parametrize("arch", ["yi-6b", "mamba2-130m", "zamba2-1.2b",
+                                  "zamba2-7b"])
 def test_decode_matches_prefill_logits(arch):
     """Greedy decode after prefill must agree with a longer prefill —
     cache correctness across families (attention, SSM, hybrid)."""
