@@ -17,6 +17,7 @@ import functools
 import jax
 
 from repro.kernels import ref as _ref
+from repro.kernels import ssd_intra
 
 SCOPES = ("embed", "norm", "proj", "conv", "ssd", "attention", "mlp",
           "logits_ce", "adamw", "shared_block", "adapter")
@@ -94,12 +95,27 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset=0, kv_len=None,
 @scoped("ssd")
 def ssd(x, dt, A, Bm, Cm, *, chunk: int = 128, init_state=None,
         return_state: bool = False, use_pallas: bool = False):
+    """The chunked scan.  Where the program runs on a TPU and the shapes
+    allow it (``ssd_intra.kernel_takes``), each chunk's state and output
+    come from the Pallas kernels of ``ssd_intra`` and the recurrence over
+    the chunks from ``ssd_ref``'s XLA code; elsewhere ``ssd_ref`` whole.
+    ``use_pallas`` takes the forward-only sequential kernel
+    ``ssd_pallas``."""
     if use_pallas:
         from repro.kernels.ssd_scan import ssd_pallas
         return ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk,
                           init_state=init_state, return_state=return_state)
-    return _ref.ssd_ref(x, dt, A, Bm, Cm, chunk=chunk,
-                        init_state=init_state, return_state=return_state)
+    xla = functools.partial(_ref.ssd_ref, chunk=chunk,
+                            return_state=return_state)
+    kb = ssd_intra.kernel_takes(x.shape, Bm.shape[2], Bm.shape[3], chunk)
+    if kb is None:
+        return xla(x, dt, A, Bm, Cm, init_state=init_state)
+    tpu = functools.partial(ssd_intra.ssd_kernel_path, chunk=chunk, kb=kb,
+                            return_state=return_state, interpret=False)
+    return jax.lax.platform_dependent(
+        x, dt, A, Bm, Cm, init_state,
+        tpu=lambda *a: tpu(*a[:5], init_state=a[5]),
+        default=lambda *a: xla(*a[:5], init_state=a[5]))
 
 
 @scoped("ssd")

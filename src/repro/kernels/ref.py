@@ -13,7 +13,7 @@ score matrix — HLO bytes reflect a production attention implementation.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -330,6 +330,8 @@ def ssd_ref(
     chunk: int = 128,
     init_state: Optional[jax.Array] = None,  # (B, H, P, N)
     return_state: bool = False,
+    chunk_states: Optional[Callable] = None,
+    chunk_output: Optional[Callable] = None,
 ):
     """Chunked SSD: y[t] = C[t] . h[t],
     h[t] = exp(dt[t] A) h[t-1] + dt[t] B[t] x[t].
@@ -340,6 +342,14 @@ def ssd_ref(
     per-position scale factors folded into one operand first; a product
     of more operands is lowered by XLA as an elementwise loop, not on the
     matrix unit.
+
+    ``chunk_states`` and ``chunk_output``, where given, compute in place
+    of the XLA code here each chunk's state, ``chunk_states(x, dt, dA_cs,
+    Bm)`` -> (B, C, G, K, P, N) float32, and each chunk's output, its
+    intra-chunk and inter-chunk parts, ``chunk_output(x, dt, dA_cs, Bm, Cm,
+    h_prev)`` -> (B, C, Q, G, K, P) in x's dtype; on the padded inputs,
+    the within-chunk cumulative sums (B, C, Q, G, K) of dt * A and the
+    state entering each chunk (``ssd_intra``).
     """
     B_, S, H, P = x.shape
     _, _, G, N = Bm.shape
@@ -366,16 +376,20 @@ def ssd_ref(
     xdt = xc * dtc[..., None]                     # (B, C, Q, G, K, P)
 
     # ---- intra-chunk (diagonal blocks) ----
-    L = jnp.exp(_segsum(dA.transpose(0, 1, 3, 4, 2)))  # (B, C, G, K, Q, Q)
-    # scores: C[l] . B[s] per head group
-    CB = jnp.einsum("bclgn,bcsgn->bcgls", Cc, Bc)   # (B, C, G, Q, Q)
-    M = CB[:, :, :, None] * L                        # decay-weighted
-    y_intra = jnp.einsum("bcgkls,bcsgkp->bclgkp", M, xdt)
+    if chunk_output is None:
+        L = jnp.exp(_segsum(dA.transpose(0, 1, 3, 4, 2)))  # (B,C,G,K,Q,Q)
+        # scores: C[l] . B[s] per head group
+        CB = jnp.einsum("bclgn,bcsgn->bcgls", Cc, Bc)   # (B, C, G, Q, Q)
+        M = CB[:, :, :, None] * L                        # decay-weighted
+        y_intra = jnp.einsum("bcgkls,bcsgkp->bclgkp", M, xdt)
 
     # ---- chunk states ----
-    decay_to_end = jnp.exp(dA_cs[:, :, -1:] - dA_cs)  # (B, C, Q, G, K)
-    states = jnp.einsum("bcsgn,bcsgkp->bcgkpn",
-                        Bc, xdt * decay_to_end[..., None])
+    if chunk_states is None:
+        decay_to_end = jnp.exp(dA_cs[:, :, -1:] - dA_cs)  # (B, C, Q, G, K)
+        states = jnp.einsum("bcsgn,bcsgkp->bcgkpn",
+                            Bc, xdt * decay_to_end[..., None])
+    else:
+        states = chunk_states(x, dt, dA_cs, Bm)
 
     # ---- inter-chunk recurrence ----
     chunk_decay = jnp.exp(jnp.sum(dA, axis=2))  # (B, C, G, K)
@@ -393,11 +407,14 @@ def ssd_ref(
     # (B, C, G, K, P, N) state BEFORE chunk
     h_prev = jnp.moveaxis(h_prev, 0, 1)
 
-    # ---- inter-chunk output ----
-    y_inter = (jnp.einsum("bclgn,bcgkpn->bclgkp", Cc, h_prev)
-               * jnp.exp(dA_cs)[..., None])
-
-    y = (y_intra + y_inter).reshape(B_, Sp, H, P)[:, :S]
+    if chunk_output is None:
+        # ---- inter-chunk output ----
+        y_inter = (jnp.einsum("bclgn,bcgkpn->bclgkp", Cc, h_prev)
+                   * jnp.exp(dA_cs)[..., None])
+        y = y_intra + y_inter
+    else:
+        y = chunk_output(x, dt, dA_cs, Bm, Cm, h_prev)
+    y = y.reshape(B_, Sp, H, P)[:, :S]
     y = y.astype(x.dtype)
     if return_state:
         return y, h_last.reshape(B_, H, P, N)

@@ -133,11 +133,14 @@ def block_fwd(p: Params, cfg: ModelConfig, run: RunConfig, x: jax.Array,
         y, new_ssm = ops.ssd(xh, dtp, A, Bg, Cg, chunk=cfg.ssm_chunk,
                              init_state=init, return_state=True,
                              use_pallas=run.use_pallas)
-    with jax.named_scope("ssd"):
-        y = y + (xh.astype(jnp.float32)
-                 * p["D"][None, None, :, None]).astype(y.dtype)
     y = constrain(y, "batch", None, "heads_ssm", "ssm_p")
     y = y.reshape(Bb, S, H * P)
+    # the skip term on the flat view: with P narrower than 128 lanes, an
+    # elementwise (..., H, P) pass leads XLA to lay y out with S minor and
+    # copy the SSD's output into that layout and back
+    with jax.named_scope("ssd"):
+        y = y + (xs.astype(jnp.float32)
+                 * jnp.repeat(p["D"], P)).astype(y.dtype)
 
     with jax.named_scope("norm"):
         y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)

@@ -30,6 +30,7 @@ from repro.kernels.ref import (attention_naive, cross_entropy_direct_ref,
                                cross_entropy_blockwise_ref,
                                flash_attention_ref, rmsnorm_ref,
                                ssd_decode_ref, ssd_ref, ssd_sequential_ref)
+from repro.kernels import ops, ssd_intra
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_pallas
 
@@ -260,6 +261,103 @@ def test_ssd_pallas_property(s, chunk, seed):
     y2, h2 = ssd_sequential_ref(x, dt, A, Bm, Cm)
     np.testing.assert_allclose(y1, y2, rtol=5e-4, atol=5e-4)
     np.testing.assert_allclose(h1, h2, rtol=5e-4, atol=5e-4)
+
+
+# ------------------------------------------------ SSD chunk-local kernels
+
+SSD_INTRA_CASES = [
+    # B, S, H, P, G, N, chunk, heads per block
+    (1, 256, 8, 64, 1, 32, 128, 4),   # two blocks of two sub-blocks
+    (1, 200, 8, 64, 2, 16, 128, 2),   # two groups of two blocks; ragged
+    (1, 512, 4, 64, 1, 16, 256, 4),
+    (1, 256, 8, 64, 2, 16, 256, 2),
+    (2, 128, 8, 32, 1, 16, 128, 8),   # four heads a sub-block
+]
+
+
+def _ssd_inputs(B, S, H, P, G, N, seed=7):
+    rng = np.random.default_rng(seed)
+    normal = lambda *shape: rng.standard_normal(shape, np.float32)
+    return (normal(B, S, H, P) * 0.5, np.log1p(np.exp(normal(B, S, H))),
+            -np.exp(normal(H) * 0.3), normal(B, S, G, N) * 0.3,
+            normal(B, S, G, N) * 0.3, normal(B, H, P, N) * 0.3), \
+        normal(B, S, H, P)
+
+
+def _ssd_grads(fn, args, wy):
+    """The output and the gradients for x, dt, A, B, C and the initial
+    state of a loss on it; ``fn(x, dt, A, Bm, Cm, init_state)``."""
+    loss = lambda *a: jnp.sum(fn(*a) * wy)
+    return (fn(*args),) + jax.grad(loss, argnums=range(6))(*args)
+
+
+SSD_GRAD_NAMES = ("y", "x", "dt", "A", "B", "C", "init_state")
+
+
+@pytest.mark.parametrize("case", SSD_INTRA_CASES)
+def test_ssd_intra_kernel_matches_ref(case):
+    """The Pallas kernels (interpret mode, float32 dots) in ``ssd_ref``'s
+    place, for each chunk's state and output: the output and every
+    input's gradient."""
+    B, S, H, P, G, N, chunk, kb = case
+    args, wy = _ssd_inputs(B, S, H, P, G, N)
+    kernel = lambda *a: ssd_intra.ssd_kernel_path(
+        *a[:5], init_state=a[5], chunk=chunk, kb=kb, mxu=jnp.float32,
+        interpret=True)
+    got = _ssd_grads(kernel, args, wy)
+    want = _ssd_grads(
+        lambda *a: ssd_ref(*a[:5], init_state=a[5], chunk=chunk), args, wy)
+    for name, a, b in zip(SSD_GRAD_NAMES, got, want):
+        scale = float(np.max(np.abs(b)))
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5 * scale,
+                                   err_msg=name)
+
+
+def test_ssd_kernel_path_under_checkpoint_matches_xla():
+    """Under ``jax.checkpoint`` (the layers' remat), with bfloat16 x, B and
+    C as the model has them, the kernel path's gradients are the XLA
+    path's."""
+    (x, dt, A, Bm, Cm, h0), wy = _ssd_inputs(1, 256, 8, 64, 2, 16)
+    bf = lambda a: jnp.asarray(a, jnp.bfloat16)
+    args = (bf(x), dt, A, bf(Bm), bf(Cm), h0)
+    kernel = jax.checkpoint(lambda *a: ssd_intra.ssd_kernel_path(
+        *a[:5], init_state=a[5], chunk=128, kb=2, mxu=jnp.float32,
+        interpret=True))
+    xla = jax.checkpoint(
+        lambda *a: ssd_ref(*a[:5], init_state=a[5], chunk=128))
+    f32 = lambda f: (lambda *a: f(*a).astype(jnp.float32))
+    got = _ssd_grads(f32(kernel), args, wy)
+    want = _ssd_grads(f32(xla), args, wy)
+    for name, a, b in zip(SSD_GRAD_NAMES, got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        # x, B and C's gradients are bfloat16: one rounding apart at most
+        np.testing.assert_allclose(a, b, rtol=1e-2,
+                                   atol=1e-3 * float(np.max(np.abs(b))),
+                                   err_msg=name)
+
+
+def test_ops_ssd_off_the_tpu_is_ssd_ref():
+    """Where the program does not run on a TPU, ``ops.ssd`` is ``ssd_ref``
+    bit for bit, at shapes the kernel takes too."""
+    (x, dt, A, Bm, Cm, _), _ = _ssd_inputs(2, 256, 4, 64, 1, 32)
+    assert ssd_intra.kernel_takes(x.shape, 1, 32, 128) == 4
+    y1, h1 = ops.ssd(x, dt, A, Bm, Cm, chunk=128, return_state=True)
+    y2, h2 = ssd_ref(x, dt, A, Bm, Cm, chunk=128, return_state=True)
+    np.testing.assert_array_equal(y1, y2)
+    np.testing.assert_array_equal(h1, h2)
+
+
+@pytest.mark.parametrize("shape,kb", [
+    ((4, 2048, 24, 64, 1, 128, 256), 8),    # mamba2-130m
+    ((4, 2048, 112, 64, 2, 64, 256), 8),    # zamba2-7b
+    ((4, 2048, 8, 128, 1, 64, 128), 4),     # one head a sub-block
+    ((4, 2048, 24, 64, 1, 128, 64), None),  # chunk not a multiple of 128
+    ((4, 2048, 6, 16, 1, 16, 128), None),   # no 128-lane block in a group
+    ((4, 2048, 24, 64, 1, 128, 1024), None),  # tiles past VMEM
+])
+def test_ssd_intra_takes_shapes(shape, kb):
+    B, S, H, P, G, N, chunk = shape
+    assert ssd_intra.heads_per_block(H, G, P, N, chunk) == kb
 
 
 # ------------------------------------------------------------------ CE

@@ -1,22 +1,25 @@
 """Compile-only checks for TPU v5e: the four Pallas kernels (Mosaic,
-interpret=False), the full-width mamba2-130m train step and the XLA SSD
-path at the training cell's widths, compiled for a described ``v5e:2x2``
-topology.  Nothing runs; this catches what the chip's compiler refuses
-(block tiling, VMEM, HBM) or lowers off the matrix unit, without a chip.
+interpret=False), the mamba2-130m train step and the XLA SSD path at the
+training cell's widths, and the SSD kernels on a four-chip mesh, compiled
+for a described ``v5e:2x2`` topology.  Nothing runs; this catches what
+the chip's compiler refuses (block tiling, VMEM, HBM) or lowers off the
+matrix unit, without a chip.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library."""
+import math
 import os
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.configs.base import RunConfig, ShapeConfig, get_config
 from repro.kernels.cross_entropy import cross_entropy_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels import ops
 from repro.kernels.ops import SCOPES
 from repro.kernels.ref import ssd_ref
 from repro.kernels.rmsnorm import rmsnorm_pallas
@@ -83,19 +86,19 @@ def test_kernel_compiles_for_v5e(one_chip, name, fn, shapes):
 
 @pytest.fixture(scope="module")
 def mamba2_step(topo):
-    """The step run_training builds, at published widths and depth (24
-    layers, seq 2048, batch 8), compiled on a one-chip mesh of the
-    described topology."""
-    cfg = get_config("mamba2-130m")
-    run = RunConfig(total_steps=10, warmup_steps=2,
-                    ce_block_v=cfg.vocab_size // 8)
+    """The step run_training builds for the training cell: mamba2-130m at
+    published widths and depth (24 layers, its chunk_size 256, the
+    vocabulary padded to 50288), 32 rows of 2048 tokens, compiled on a
+    one-chip mesh of the described topology."""
+    cfg = get_config("mamba2-130m").replace(ssm_chunk=256, vocab_size=50288)
+    run = RunConfig(total_steps=10, warmup_steps=2, ce_block_v=6286)
     rules = ShardingRules(make_mesh((1, 1), ("data", "model"),
                                     devices=topo.devices[:1]))
     st_sh = state_shardings(cfg, rules)
     with_sharding = lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                                        sharding=sh)
     state = jax.tree.map(with_sharding, abstract_state(cfg, run), st_sh)
-    batch = registry.train_input_specs(cfg, ShapeConfig("t", 2048, 8,
+    batch = registry.train_input_specs(cfg, ShapeConfig("t", 2048, 32,
                                                         "train"))
     batch = jax.tree.map(with_sharding, batch, batch_shardings(rules, batch))
     with use_rules(rules):
@@ -119,6 +122,65 @@ def test_mamba2_130m_train_step_matmuls_are_scoped(mamba2_step):
     for ln in convs:
         m = re.search(r'op_name="([^"]*)"', ln)
         assert m and set(re.split(r"[/()]", m.group(1))) & set(SCOPES), ln
+
+
+def test_mamba2_130m_train_step_runs_the_ssd_kernels(mamba2_step):
+    """On the TPU the chunk scan's chunk-local parts (each chunk's state,
+    and its output within and from before the chunk) run in the Pallas
+    kernels, forward (twice: the pass and its recomputation) and
+    backward, under the ``ssd`` scope."""
+    hlo = mamba2_step.as_text()
+    for name in ("ssd_intra_fwd", "ssd_intra_bwd", "ssd_states_fwd",
+                 "ssd_states_bwd"):
+        calls = re.findall(rf"%{name}[.\d]* = .* custom-call\(.*", hlo)
+        assert calls, name
+        for ln in calls:
+            assert "/ssd/" in re.search(r'op_name="([^"]*)"', ln)[1], ln
+
+
+def test_mamba2_130m_train_step_forms_no_per_head_score_tiles(mamba2_step):
+    """No float32 value of the program is as large as one (Q, Q) tile per
+    head and chunk of the batch, as ``ssd_ref``'s decay and score matrices
+    are: only the cross entropy's (tokens, vocabulary block) logits."""
+    B, C, H, Q = 32, 2048 // 256, 24, 256
+    big = set()
+    for m in re.finditer(r"= \(?f32\[([\d,]+)\]", mamba2_step.as_text()):
+        dims = [int(d) for d in m[1].split(",")]
+        if math.prod(dims) >= B * C * H * Q * Q:
+            big.add(tuple(dims))
+    assert big <= {(B * 2048, 6286)}, big
+
+
+def test_mamba2_130m_train_step_peak_memory(mamba2_step):
+    """The kernels' step needs no more device memory than the XLA path's
+    (8,231,715,840 bytes compiled with ``ssd_ref`` in the scan's place)."""
+    assert mamba2_step.memory_analysis().peak_memory_in_bytes <= 8_231_715_840
+
+
+def test_ssd_kernels_on_a_four_chip_mesh_gather_nothing(topo):
+    """Under the rules of a (4, 1) mesh with the batch on "data", the
+    kernels run on each chip's own rows: the SSD's forward and backward
+    pass hold no all-gather, all-to-all or permute of an operand."""
+    rules = ShardingRules(make_mesh((4, 1), ("data", "model"),
+                                    devices=topo.devices))
+    B, S, H, P, G, N = 8, 512, 8, 64, 2, 16
+    rows = NamedSharding(rules.mesh, PartitionSpec("data"))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d, sh in
+            [((B, S, H, P), BF, rows), ((B, S, H), F32, rows),
+             ((H,), F32, NamedSharding(rules.mesh, PartitionSpec())),
+             ((B, S, G, N), BF, rows), ((B, S, G, N), BF, rows)]]
+
+    def loss(*a):
+        return jnp.sum(ops.ssd(*a, chunk=128).astype(F32))
+
+    with use_rules(rules):
+        hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+                      ).lower(*args).compile().as_text()
+    for name in ("ssd_intra_fwd", "ssd_intra_bwd", "ssd_states_fwd",
+                 "ssd_states_bwd"):
+        assert name in hlo, name
+    for op in ("all-gather", "all-to-all", "collective-permute"):
+        assert f" {op}(" not in hlo and f" {op}-start(" not in hlo, op
 
 
 # B, S, H, P, G, N, chunk of the training cell: mamba2-130m, 32 rows of
